@@ -20,6 +20,8 @@ import json
 import platform
 import sys
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (bench_aggregation, bench_kernels, bench_mapreduce,
                bench_overlap, bench_plan, bench_prefix, bench_serve,
                bench_sketches, bench_train, bench_windows)
@@ -186,6 +188,7 @@ def main(argv=None) -> int:
                     help="with --compare: a missing/unreadable baseline is "
                          "an error, not a silent skip")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     if args.prefix:

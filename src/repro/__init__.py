@@ -18,9 +18,7 @@ The core fold API is re-exported here — ``repro.Monoid``,
 ``repro.execute_fold``, ``repro.plan_fold``, ``repro.MapReduceJob`` — so
 applications don't import from ``repro.core.plan`` internals.
 """
-from . import _compat  # noqa: F401  (installs jax API shims; must run first)
-
-from .core import (MapReduceJob, Monoid, execute_fold, monoids,  # noqa: E402
+from .core import (MapReduceJob, Monoid, execute_fold, monoids,
                    plan_fold)
 
 __version__ = "0.1.0"

@@ -302,7 +302,9 @@ class MapReduceJob:
         """The execution plan for this job's per-shard fold + shuffle.
 
         The plan is built from ShapeDtypeStructs (no FLOPs): one shard's
-        lifted pairs, keyed by ``num_keys``, combined across the
+        raw mapper values (lifted inside the fold, as the combiner and
+        in-mapper strategies run them), keyed by ``num_keys``, combined
+        across the
         ``axis_name`` mesh axis of size ``num_shards`` (pass the real axis
         name so :meth:`run_sharded` executes exactly this plan).
         strategy='naive' models Algorithm 1 (``pre_combine=False``: raw
@@ -317,12 +319,12 @@ class MapReduceJob:
             raise ValueError(f"strategy must be one of {STRATEGIES}")
         n = jax.tree_util.tree_leaves(records)[0].shape[0]
         local_n = max(1, n // num_shards)
-        one_rec = jax.tree_util.tree_map(lambda x: x[0], records)
-        _, raw_shape = jax.eval_shape(self.mapper, one_rec)
-        value_shape = jax.eval_shape(self.monoid.lift, raw_shape)
+        _, raw_shape = jax.eval_shape(
+            lambda r: self.mapper(jax.tree_util.tree_map(lambda x: x[0], r)),
+            records)
         pairs = jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct((local_n,) + s.shape, s.dtype),
-            value_shape)
+            raw_shape)
         seg = jax.ShapeDtypeStruct((local_n,), jnp.int32)
         shard_mask = None
         if valid_mask is not None:
@@ -336,7 +338,7 @@ class MapReduceJob:
             valid_mask=shard_mask,
             mesh_axes=(axis_name,), axis_sizes={axis_name: num_shards},
             layout="scan" if strategy == "in_mapper" else "auto",
-            pre_combine=strategy != "naive")
+            lifted=False, pre_combine=strategy != "naive")
 
     def stats(self, records: Pytree, *, strategy: str, num_shards: int,
               valid_mask=None) -> ShuffleStats:
@@ -394,13 +396,15 @@ def algorithm2_combiner(t_and_r, _ignored):
 
 
 def word_count_job(vocab: int) -> MapReduceJob:
-    """The canonical MapReduce hello-world as a monoid job."""
+    """The canonical MapReduce hello-world as a monoid job: the count
+    monoid lifts every token to 1, so the planner knows each key's total is
+    bounded by the token count (exact on the kernel tier below 2**24)."""
     from . import monoids
 
     def mapper(token):
-        return token, jnp.ones((), jnp.int32)
+        return token, token
 
-    return MapReduceJob(mapper=mapper, monoid=monoids.sum_, num_keys=vocab)
+    return MapReduceJob(mapper=mapper, monoid=monoids.count, num_keys=vocab)
 
 
 def cooccurrence_stripes_job(vocab: int, window: int) -> MapReduceJob:

@@ -31,6 +31,7 @@ monoids get leaf-wise semiring lowerings below.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -42,6 +43,9 @@ from .aggregation import _PMAX_LIKE, _PMIN_LIKE, _PSUM_LIKE, tree_bytes
 from .calibration import Calibration, get_calibration, pipeline_exposed_us
 
 LAYOUTS = ("auto", "kernel", "segment", "scan", "tree", "async")
+
+# records per grid step of the Pallas kernel tier
+BLOCK_N = 512
 
 # layout spelling (user-facing) -> calibration tier kind (TierPlan.kind).
 # 'async' is absent on purpose: it is a whole-plan shape (fused local +
@@ -76,7 +80,7 @@ def _semiring_lowering(semiring: str) -> KernelLowering:
     """
 
     def lower(values: Pytree, seg_ids: jnp.ndarray, num_segments: int, *,
-              block_n: int = 512, valid_mask: Optional[jnp.ndarray] = None,
+              block_n: int = BLOCK_N, valid_mask: Optional[jnp.ndarray] = None,
               interpret: Optional[bool] = None) -> Pytree:
         from ..kernels.segment_fold import segment_fold_pallas
 
@@ -101,7 +105,7 @@ def _mean_pair_lowering() -> KernelLowering:
     leafwise = _semiring_lowering("sum").fn
 
     def lower(values: Pytree, seg_ids: jnp.ndarray, num_segments: int, *,
-              block_n: int = 512, valid_mask: Optional[jnp.ndarray] = None,
+              block_n: int = BLOCK_N, valid_mask: Optional[jnp.ndarray] = None,
               interpret: Optional[bool] = None) -> Pytree:
         from ..kernels.segment_fold import segment_fold_pallas
 
@@ -198,6 +202,9 @@ class Plan:
     plan_candidate_us: Tuple[Tuple[str, float], ...] = ()
                                        # whole-plan (sync vs async) argmin
                                        #   table when both shapes were priced
+    kernel_infeasible: Optional[str] = None
+                                       # why the kernel tier was left out on
+                                       #   a TPU backend (fast memory, dtype)
 
     @property
     def local_tier(self) -> TierPlan:
@@ -249,6 +256,8 @@ class Plan:
                   f" vs {self.dense_wire_bytes}B dense]")
         if self.overlap_modeled > 0.0:
             s += f" [overlap modeled {self.overlap_modeled:.0%}]"
+        if self.kernel_infeasible:
+            s += f" [kernel infeasible: {self.kernel_infeasible}]"
         return s
 
 
@@ -361,13 +370,40 @@ def _mask_segment_ids(segment_ids: jnp.ndarray, valid_mask,
                      num_segments)
 
 
-def _kernel_infeasible_reason(m: Monoid, value_shape: Pytree) -> Optional[str]:
+# Mosaic's default scoped fast-memory (VMEM) limit on TPU v5e, the smallest
+# of the TPU generations: a kernel whose live blocks exceed it is refused by
+# the compiler (RESOURCE_EXHAUSTED), so the planner refuses it first.
+_KERNEL_VMEM_BYTES = 16 * 2**20
+
+
+def _kernel_vmem_bytes(semiring: str, num_segments: int, width: int,
+                       block_n: int) -> int:
+    """Fast memory the segment-fold kernel keeps live for one leaf of
+    ``width`` columns (f32; tiles of 8 sublanes x 128 lanes): the one-hot
+    (S, BN) mask of the additive path or the (8, BN, D) select of max/min,
+    the resident (S, D) output block, and the double-buffered input blocks
+    (records on the lanes for sum, on the sublanes for max/min)."""
+    lanes = -(-width // 128) * 128
+    if semiring == "sum":
+        work, rows = num_segments * block_n, num_segments
+        inputs = (-(-width // 8) * 8 + 8) * block_n
+    else:
+        work, rows = 8 * block_n * lanes, -(-num_segments // 8) * 8
+        inputs = block_n * (lanes + 128)
+    return 4 * (work + rows * lanes + 2 * inputs)
+
+
+def _kernel_infeasible_reason(m: Monoid, value_shape: Pytree,
+                              num_segments: int,
+                              block_n: int) -> Optional[str]:
     """Why the kernel tier cannot lower this fold — None when it can.
 
     The returned text names the offending leaf (tree path) and its dtype,
-    so a forced ``layout='kernel'`` fails at PLAN time with an actionable
-    message instead of deep inside the Pallas lowering."""
-    if m.kernel_lowering() is None:
+    or the fast memory the kernel would need, so a forced
+    ``layout='kernel'`` fails at PLAN time with an actionable message
+    instead of deep inside the Pallas lowering or the TPU compiler."""
+    low = m.kernel_lowering()
+    if low is None:
         return (f"monoid {m.name!r} has no registered Pallas kernel lowering "
                 "(see register_kernel_lowering)")
     leaves, _ = jax.tree_util.tree_flatten_with_path(value_shape)
@@ -378,14 +414,19 @@ def _kernel_infeasible_reason(m: Monoid, value_shape: Pytree) -> Optional[str]:
             return (f"value leaf {where!r} has dtype "
                     f"{jnp.dtype(leaf.dtype).name}, which the Pallas "
                     "segment-fold kernel cannot lower (float/int leaves only)")
+    # mean's count column rides its sums' kernel call
+    width = max(math.prod(leaf.shape) for _, leaf in leaves) + (
+        m.name == "mean")
+    need = _kernel_vmem_bytes(low.semiring, num_segments, width, block_n)
+    if need > _KERNEL_VMEM_BYTES:
+        return (f"{num_segments} keys x {width} columns at block_n={block_n} "
+                f"need {need / 2**20:.1f} MiB of kernel fast memory, over "
+                f"the {_KERNEL_VMEM_BYTES / 2**20:.0f} MiB limit")
     return None
 
 
-def _kernel_compatible(m: Monoid, value_shape: Pytree) -> bool:
-    return _kernel_infeasible_reason(m, value_shape) is None
-
-
-def _kernel_exact(value_shape: Pytree, num_records: int) -> bool:
+def _kernel_exact(value_shape: Pytree, num_records: int,
+                  unit: bool = False) -> bool:
     """Whether the kernel's float32 accumulator is exact for these inputs.
 
     Integer leaves are accumulated in float32; that is exact only while the
@@ -393,16 +434,18 @@ def _kernel_exact(value_shape: Pytree, num_records: int) -> bool:
     plan time, so ``layout='auto'`` only keeps integer inputs on the kernel
     tier when even the worst case (every record at the dtype's extreme, all
     landing in one key) fits — narrow dtypes (8/16-bit bitmaps and counts)
-    pass for reasonable batches; 32-bit-and-wider integers always down-tier
-    to the exact segment-ops path.  Forcing ``layout='kernel'`` bypasses
-    this — the caller asserts their magnitudes fit.
+    pass for reasonable batches; 32-bit-and-wider integers down-tier to the
+    exact segment-ops path.  ``unit`` says every record is known to be 1
+    (a count's lift), so the bound is the record count itself.  Forcing
+    ``layout='kernel'`` bypasses this — the caller asserts their
+    magnitudes fit.
     """
     for leaf in jax.tree_util.tree_leaves(value_shape):
         if jnp.issubdtype(leaf.dtype, jnp.integer):
             info = jnp.iinfo(leaf.dtype)
             # extreme magnitude: unsigned dtypes have info.min == 0, so the
             # bound must come from info.max there
-            extreme = max(abs(int(info.min)), int(info.max))
+            extreme = 1 if unit else max(abs(int(info.min)), int(info.max))
             if extreme * max(num_records, 1) >= 2 ** 24:
                 return False
     return True
@@ -532,7 +575,7 @@ def plan_fold(m: Monoid, values: Pytree, *, segment_ids=None,
               map_fn: Optional[Callable] = None,
               mesh: Optional[jax.sharding.Mesh] = None,
               axis_sizes: Optional[Mapping[Any, int]] = None,
-              pre_combine: bool = True, block_n: int = 512,
+              pre_combine: bool = True, block_n: int = BLOCK_N,
               lossy=None,
               calibration: Optional[Calibration] = None) -> Plan:
     """Lower a fold to a tiered :class:`Plan` without executing it.
@@ -605,6 +648,7 @@ def plan_fold(m: Monoid, values: Pytree, *, segment_ids=None,
                 "no crossing to overlap")
 
     n = _leading_dim(values)
+    kernel_note = None
     if valid_mask is not None:
         _check_valid_mask(valid_mask, n)
     num_valid = _static_valid_count(valid_mask)
@@ -630,12 +674,16 @@ def plan_fold(m: Monoid, values: Pytree, *, segment_ids=None,
         if layout == "tree":
             raise ValueError("layout='tree' is a flat-fold layout; keyed "
                              "folds use kernel/segment/scan")
-        kernel_reason = _kernel_infeasible_reason(m, value_shape)
+        kernel_reason = _kernel_infeasible_reason(m, value_shape,
+                                                  num_segments, block_n)
+        # count lifts every raw record to 1: its per-key total is <= n
+        unit = m.name == "count" and (not lifted or map_fn is not None)
         candidates = []
         # feasibility only: the kernel tier needs a registered lowering with
-        # compatible dtypes, an exact accumulator, and the TPU backend —
-        # WHICH feasible tier runs is the cost model's call below
-        if (kernel_reason is None and _kernel_exact(value_shape, n_model)
+        # compatible dtypes that fits fast memory, an exact accumulator, and
+        # the TPU backend — WHICH feasible tier runs is the cost model's
+        # call below
+        if (kernel_reason is None and _kernel_exact(value_shape, n_model, unit)
                 and jax.default_backend() == "tpu"):
             candidates.append("kernel")
         if m.name in _SEGMENT_OPS:
@@ -647,6 +695,9 @@ def plan_fold(m: Monoid, values: Pytree, *, segment_ids=None,
         costs = dict(candidate_us)
         kind = (min(candidates, key=costs.get) if layout == "auto"
                 else layout)
+        if (kernel_reason is not None and m.kernel_lowering() is not None
+                and jax.default_backend() == "tpu"):
+            kernel_note = kernel_reason
         if kind == "kernel":
             if kernel_reason is not None:
                 raise ValueError(
@@ -787,7 +838,8 @@ def plan_fold(m: Monoid, values: Pytree, *, segment_ids=None,
                 lossy=spec.describe() if spec else None,
                 overlap_modeled=overlap_modeled,
                 dense_wire_bytes=dense_wire, lossy_wire_bytes=lossy_wire,
-                plan_candidate_us=plan_cand)
+                plan_candidate_us=plan_cand,
+                kernel_infeasible=kernel_note)
 
 
 # ---------------------------------------------------------------------------
@@ -900,7 +952,7 @@ def execute_fold(m: Monoid, values: Pytree, *, segment_ids=None,
                  layout: str = "auto", lifted: bool = True,
                  map_fn: Optional[Callable] = None,
                  init: Optional[Pytree] = None, axis: int = 0,
-                 block_n: int = 512, interpret: Optional[bool] = None,
+                 block_n: int = BLOCK_N, interpret: Optional[bool] = None,
                  mesh: Optional[jax.sharding.Mesh] = None,
                  axis_sizes: Optional[Mapping[Any, int]] = None,
                  lossy=None, ef: Optional[Pytree] = None,
@@ -1074,7 +1126,8 @@ def segment_fold(m: Monoid, values: Pytree, segment_ids: jnp.ndarray,
         if m.name not in ("sum", "mean", "count"):
             raise ValueError("onehot impl is only meaningful for additive monoids")
         if (jax.default_backend() == "tpu"
-                and _kernel_compatible(m, _one_slice(values))):
+                and _kernel_infeasible_reason(m, _one_slice(values),
+                                              num_segments, BLOCK_N) is None):
             out = execute_fold(m, values, segment_ids=segment_ids,
                                num_segments=num_segments, init=init,
                                layout="kernel")

@@ -48,8 +48,12 @@ def _cms_kernel(tok_ref, mask_ref, out_ref, *, depth: int, width: int,
 
 
 def cms_update_pallas(tokens: jnp.ndarray, depth: int, width: int, *,
-                      block_n: int = 1024, interpret: bool = True) -> jnp.ndarray:
+                      block_n: int = 1024, interpret: bool | None = None
+                      ) -> jnp.ndarray:
     """tokens: (N,) int -> (depth, width) float32 counts."""
+    if interpret is None:
+        from .ops import _default_interpret
+        interpret = _default_interpret()
     N = tokens.shape[0]
     pad = (-N) % block_n
     mask = jnp.ones((N,), jnp.int32)

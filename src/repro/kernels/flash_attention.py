@@ -67,13 +67,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jnp.ndarray:
+                    block_k: int = 128, interpret: bool | None = None
+                    ) -> jnp.ndarray:
     """q: (B, H, Sq, d); k, v: (B, KV, Sk, d) with H % KV == 0.
 
     Returns (B, H, Sq, d) in q's dtype. Forward only (serving / frozen-eval;
     the training path uses the XLA-fused chunked AttnState form, which
     autodiffs — models/attention.py).
     """
+    if interpret is None:
+        from .ops import _default_interpret
+        interpret = _default_interpret()
     B, H, Sq, d = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     assert H % KV == 0

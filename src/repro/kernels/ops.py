@@ -1,11 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU — pl.pallas_call + BlockSpec VMEM tiling — and are
-validated in interpret mode against the ref.py oracles).  Set
-``REPRO_INTERPRET=1`` to force interpret mode on TPU (debugging) or
-``REPRO_INTERPRET=0`` to force compiled mode in CI; the override is read
-when the wrapper is called (i.e. at trace time for jit'd callers).
+Kernels run compiled on a TPU backend and in interpret mode everywhere else
+(the tests run on the CPU and check them against the ref.py oracles).
+``REPRO_INTERPRET=0`` forces compiled mode; ``REPRO_INTERPRET=1`` forces
+interpret mode off the TPU and is refused on it, so a chip run never
+measures the interpreter.  The setting is read when the wrapper is called
+(i.e. at trace time for jit'd callers).
 """
 from __future__ import annotations
 
@@ -25,13 +25,19 @@ _FALSY = ("0", "false", "no", "off")
 
 
 def _default_interpret() -> bool:
-    """Interpret-mode default: backend detection, REPRO_INTERPRET override."""
+    """Interpret mode off the TPU, compiled kernels on it; REPRO_INTERPRET
+    overrides, except that interpret mode is refused on a TPU backend."""
     env = os.environ.get("REPRO_INTERPRET", "").strip().lower()
+    on_tpu = jax.default_backend() == "tpu"
     if env in _TRUTHY:
+        if on_tpu:
+            raise RuntimeError(
+                "REPRO_INTERPRET=1 asks for interpret-mode kernels on a TPU "
+                "backend; the chip runs compiled kernels only")
         return True
     if env in _FALSY:
         return False
-    return jax.default_backend() != "tpu"
+    return not on_tpu
 
 
 @partial(jax.jit, static_argnames=("num_segments", "with_count", "block_n",

@@ -20,8 +20,15 @@ whole additive/max-plus monoid family the planner (core/plan.py) registers:
   appends a ones column so mean's two components ride one matmul.
 * ``'max'`` / ``'min'`` — the max-plus family (max, min, and 0/1-bitmap
   bitwise_or): the one-hot mask selects block rows per segment and the VPU
-  takes the running max/min.  This path materializes an (S, BN, D) select,
-  so prefer a smaller ``block_n`` than the additive default.
+  takes the running max/min, eight segments at a time, so the select is an
+  (8, BN, D) block whatever the key count.
+
+Segment ids enter as a (1, N) row on the additive path (a (1, BN) block
+matches XLA's lane tiling at any N; a 1-D (BN,) block is refused once N
+exceeds it) and as an (N, 1) column on the max/min path, where the mask
+must broadcast along the value lanes.  The planner
+(``core/plan.py:_kernel_vmem_bytes``) keeps the mask, the select and the
+resident (S, D) output within the chip's scoped fast memory.
 
 Exact integer monoids round-trip: integer inputs are accumulated in float32
 (exact for |values| < 2**24) and cast back to the input dtype, with empty
@@ -39,6 +46,9 @@ SEMIRINGS = ("sum", "max", "min")
 
 _IDENTITY = {"sum": 0.0, "max": -jnp.inf, "min": jnp.inf}
 
+# segments per max/min select (one sublane tile)
+_MAX_ROWS = 8
+
 
 def _segment_fold_kernel(seg_ref, val_ref, out_ref, *, num_segments: int,
                          block_n: int, semiring: str):
@@ -48,22 +58,36 @@ def _segment_fold_kernel(seg_ref, val_ref, out_ref, *, num_segments: int,
     def _init():
         out_ref[...] = jnp.full_like(out_ref, _IDENTITY[semiring])
 
-    seg = seg_ref[...]                                   # (BN,)
-    vals = val_ref[...].astype(jnp.float32)              # (BN, D)
+    vals = val_ref[...].astype(jnp.float32)    # (D, BN) sum; (BN, D) max/min
     # one-hot scatter mask: padded rows carry seg id == num_segments (out of
     # range), so they match no row and contribute the semiring identity.
-    mask = seg[None, :] == jax.lax.broadcasted_iota(
-        jnp.int32, (num_segments, block_n), 0)           # (S, BN)
     if semiring == "sum":
-        out_ref[...] += jax.lax.dot(mask.astype(jnp.float32), vals,
-                                    preferred_element_type=jnp.float32)
-    else:
-        picked = jnp.where(mask[:, :, None], vals[None, :, :],
-                           _IDENTITY[semiring])          # (S, BN, D) on the VPU
-        if semiring == "max":
-            out_ref[...] = jnp.maximum(out_ref[...], picked.max(axis=1))
-        else:
-            out_ref[...] = jnp.minimum(out_ref[...], picked.min(axis=1))
+        seg = seg_ref[...]                               # (1, BN), ids on lanes
+        mask = seg == jax.lax.broadcasted_iota(
+            jnp.int32, (num_segments, block_n), 0)       # (S, BN)
+        # (S, BN) x (D, BN)^T on the MXU: records stay on the lanes
+        out_ref[...] += jax.lax.dot_general(
+            mask.astype(jnp.float32), vals, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return
+    # max/min: ids on sublanes, so the (rows, BN, 1) mask broadcasts along
+    # the lanes of (BN, D) without a relayout; _MAX_ROWS segments at a time
+    # keep the (rows, BN, D) select at a fixed size whatever num_segments is
+    seg = seg_ref[...]                                   # (BN, 1)
+    reduce = jnp.max if semiring == "max" else jnp.min
+    combine = jnp.maximum if semiring == "max" else jnp.minimum
+
+    def chunk(c, carry):
+        lo = pl.multiple_of(c * _MAX_ROWS, _MAX_ROWS)
+        rows = lo + jax.lax.broadcasted_iota(
+            jnp.int32, (_MAX_ROWS, block_n, 1), 0)
+        picked = jnp.where(seg[None, :, :] == rows, vals[None, :, :],
+                           _IDENTITY[semiring])          # (rows, BN, D)
+        cur = out_ref[pl.ds(lo, _MAX_ROWS), :]
+        out_ref[pl.ds(lo, _MAX_ROWS), :] = combine(cur, reduce(picked, axis=1))
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0] // _MAX_ROWS, chunk, 0)
 
 
 def _finish_dtype(out: jnp.ndarray, dtype, semiring: str) -> jnp.ndarray:
@@ -101,7 +125,7 @@ def segment_fold_pallas(values: jnp.ndarray, seg_ids: jnp.ndarray,
     body is untouched (one mask per grid step, zero extra FLOPs on the MXU).
 
     ``interpret=None`` resolves via :func:`repro.kernels.ops._default_interpret`
-    (TPU detection, overridable with ``REPRO_INTERPRET=0/1``).
+    (compiled on a TPU backend, interpret mode elsewhere).
     """
     if semiring not in SEMIRINGS:
         raise ValueError(f"unknown semiring {semiring!r}; one of {SEMIRINGS}")
@@ -126,18 +150,30 @@ def segment_fold_pallas(values: jnp.ndarray, seg_ids: jnp.ndarray,
         seg_ids = jnp.concatenate(
             [seg_ids, jnp.full((pad,), num_segments, seg_ids.dtype)], axis=0)
     grid = ((N + pad) // block_n,)
+    seg_ids = seg_ids.astype(jnp.int32)
+    if semiring == "sum":
+        # records on the lanes: (1, N) ids and (D, N) values, whose
+        # block_n-wide blocks match XLA's tiling at any N (a 1-D (block_n,)
+        # id block does not) and pad D to 8 sublanes, not 128 lanes
+        seg_ids, seg_spec = seg_ids[None, :], pl.BlockSpec(
+            (1, block_n), lambda i: (0, i))
+        values, val_spec = values.T, pl.BlockSpec(
+            (D, block_n), lambda i: (0, i))
+        rows = num_segments
+    else:
+        seg_ids, seg_spec = seg_ids[:, None], pl.BlockSpec(
+            (block_n, 1), lambda i: (i, 0))
+        val_spec = pl.BlockSpec((block_n, D), lambda i: (i, 0))
+        rows = -(-num_segments // _MAX_ROWS) * _MAX_ROWS
     out = pl.pallas_call(
         functools.partial(_segment_fold_kernel, num_segments=num_segments,
                           block_n=block_n, semiring=semiring),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, D), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((num_segments, D), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_segments, D), jnp.float32),
+        in_specs=[seg_spec, val_spec],
+        out_specs=pl.BlockSpec((rows, D), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
         interpret=interpret,
-    )(seg_ids.astype(jnp.int32), values)
+    )(seg_ids, values)[:num_segments]
     if with_count:
         return _finish_dtype(out[:, :-1], orig_dtype, semiring), out[:, -1]
     return _finish_dtype(out, orig_dtype, semiring)

@@ -45,9 +45,13 @@ def _stripes_kernel(tok_ref, neigh_ref, mask_ref, out_ref, *, vocab: int,
 
 
 def stripes_pallas(tokens: jnp.ndarray, vocab: int, window: int, *,
-                   block_n: int = 512, interpret: bool = True) -> jnp.ndarray:
+                   block_n: int = 512, interpret: bool | None = None
+                   ) -> jnp.ndarray:
     """tokens: (N,) int -> (vocab, vocab) symmetric co-occurrence counts
     (pairs within distance <= window, both directions)."""
+    if interpret is None:
+        from .ops import _default_interpret
+        interpret = _default_interpret()
     N = tokens.shape[0]
     # pre-gather forward neighbors: neigh[i, j] = tokens[i + j + 1]
     idx = jnp.arange(N)[:, None] + jnp.arange(1, window + 1)[None, :]

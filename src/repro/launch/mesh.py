@@ -3,7 +3,7 @@ module never touches jax device state (the dry-run sets
 ``--xla_force_host_platform_device_count`` before first jax init)."""
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 
@@ -22,14 +22,20 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence[jax.Device]] = None
+              ) -> jax.sharding.Mesh:
     return jax.make_mesh(
-        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
-def make_host_mesh(model: Optional[int] = None) -> jax.sharding.Mesh:
-    """Mesh over whatever devices exist (tests / single host): (data, model)."""
-    n = len(jax.devices())
+def make_host_mesh(model: Optional[int] = None,
+                   devices: Optional[Sequence[jax.Device]] = None
+                   ) -> jax.sharding.Mesh:
+    """(data, model) mesh over ``devices`` — every visible device when None
+    (tests / single host); pass ``jax.devices()[:1]`` to pin one chip."""
+    n = len(jax.devices() if devices is None else devices)
     model = model or 1
     assert n % model == 0
-    return make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"), devices)

@@ -27,7 +27,7 @@ import argparse
 import dataclasses
 import time
 import warnings
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,7 @@ from ..runtime.engine import (ContinuousEngine, EngineBackend, ServeConfig,
                               decode_metrics_init, decode_metrics_plan,
                               decode_metrics_step, extract_metrics,
                               METRIC_COLS)
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 from .steps import make_decode_step
 
@@ -53,30 +54,36 @@ __all__ = [
 ]
 
 
-def build_engine(config: ServeConfig, *,
-                 clock=time.perf_counter) -> ContinuousEngine:
+def build_engine(config: ServeConfig, *, clock=time.perf_counter,
+                 devices: Optional[Sequence[jax.Device]] = None
+                 ) -> ContinuousEngine:
     """A :class:`ContinuousEngine` over the real model substrate.
 
     Builds params + mesh for ``config.arch`` and hands the engine a
     traceable one-token decode (the same ``decode_step`` the dry-run
     lowers) plus a cache constructor with per-slot positions for the
     rolling cache.  Everything shape-dependent — slot count, prefill
-    bucket ladder, generation budget — comes from ``config``.
+    bucket ladder, generation budget — comes from ``config``.  The mesh
+    spans ``devices`` (every visible device when None).
     """
     cfg = get_config(config.arch, smoke=not config.full)
     if context_spec(cfg, 1) is not None:
         raise NotImplementedError(
             f"{config.arch}: context-conditioned archs (audio/vision) are "
             f"not supported by the continuous engine yet")
-    mesh = make_host_mesh(model=config.model_parallel)
+    mesh = make_host_mesh(model=config.model_parallel, devices=devices)
     rules = shd.trim_rules(shd.SERVE_RULES, mesh)
     ctx = RunCtx(mesh=mesh)
     key = jax.random.PRNGKey(config.seed)
     params, _ = init_params(cfg, key)
+    # commit params to the mesh: left uncommitted they would stay on the
+    # default device whatever ``devices`` says
     if config.model_parallel > 1:
         pshard = shd.param_shardings(param_shapes(cfg), param_axes(cfg),
                                      mesh, rules)
-        params = jax.device_put(params, pshard)
+    else:
+        pshard = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    params = jax.device_put(params, pshard)
 
     def decode(p, cache, cur):
         with shd.use_rules(mesh, rules):
@@ -283,6 +290,7 @@ def main(argv=None):
     ap.add_argument("--shared-len", type=int, default=0,
                     help="token length of the shared prefix")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     buckets = tuple(int(b) for b in args.buckets.split(","))
     if args.max_prompt > buckets[-1]:

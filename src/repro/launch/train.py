@@ -13,7 +13,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +26,7 @@ from ..data import init_stats, make_stream_stats, update_stats
 from ..models import RunCtx, init_params
 from ..optim import OptConfig, init_opt_state
 from ..runtime import PreemptionHandler
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 from .steps import make_train_step
 
@@ -52,10 +53,12 @@ class TrainerConfig:
         peak_lr=1e-3, warmup_steps=10, decay_steps=1000))
 
 
-def train(tc: TrainerConfig, *, preemption: Optional[PreemptionHandler] = None
-          ) -> Dict[str, Any]:
+def train(tc: TrainerConfig, *, preemption: Optional[PreemptionHandler] = None,
+          devices: Optional[Sequence[jax.Device]] = None) -> Dict[str, Any]:
+    """Run ``tc.steps`` train steps on a (data, model) mesh over ``devices``
+    (every visible device when None)."""
     cfg = get_config(tc.arch, smoke=tc.smoke)
-    mesh = make_host_mesh(model=tc.model_parallel)
+    mesh = make_host_mesh(model=tc.model_parallel, devices=devices)
     shape = ShapeCell("custom", "train", tc.seq_len, tc.global_batch)
     ctx = RunCtx(mesh=mesh, moe_impl=tc.moe_impl)
     built = make_train_step(cfg, mesh, shape, opt=tc.opt, ctx=ctx,
@@ -133,7 +136,8 @@ def train(tc: TrainerConfig, *, preemption: Optional[PreemptionHandler] = None
         store.wait()
     return {"history": history, "metrics_acc": metrics_acc,
             "stream_stats": stream_stats, "params": params,
-            "steps_done": step + 1 if tc.steps > start_step else start_step}
+            "steps_done": step + 1 if tc.steps > start_step else start_step,
+            "step_compiles": int(built.fn._cache_size())}
 
 
 def _metrics_like(built) -> Dict[str, jnp.ndarray]:
@@ -160,6 +164,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
     tc = TrainerConfig(arch=args.arch, smoke=not args.full, steps=args.steps,
                        global_batch=args.batch, seq_len=args.seq,
                        microbatches=args.microbatches, lossy=args.lossy,

@@ -569,10 +569,7 @@ class ContinuousEngine:
         gather per admission batch size k, one prefill per (k, bucket), and
         the prefix cache's stats fold + row reset."""
         def n(f):
-            try:
-                return int(f._cache_size())
-            except Exception:      # pragma: no cover - older jax
-                return -1
+            return int(f._cache_size())
 
         counts = {"step": n(self._step_fn)}
         for (k, b), f in self._prefill_fns.items():

@@ -233,10 +233,7 @@ class PrefixCache:
 
     def compile_counts(self) -> Dict[str, int]:
         def n(f):
-            try:
-                return int(f._cache_size())
-            except Exception:      # pragma: no cover - older jax
-                return -1
+            return int(f._cache_size())
 
         return {"prefix_stats_fold": n(self._fold_fn),
                 "prefix_row_reset": n(self._clear_fn)}

@@ -19,7 +19,6 @@ def run_distributed(code: str, devices: int = 8, timeout: int = 420):
 
 
 PRELUDE = """
-import repro  # noqa: F401  (installs the jax API compat shims first)
 import jax, jax.numpy as jnp, numpy as np
 assert len(jax.devices()) == 8, jax.devices()
 mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
@@ -477,5 +476,35 @@ for layout in ("scan", "async"):
         got = run(layout, lossy)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4,
                                    err_msg=f"{layout}/{lossy}")
+print("ok")
+""")
+
+
+def test_runs_pinned_to_chosen_devices():
+    """make_host_mesh / build_engine / train span exactly the devices they
+    are given (every visible device by default): a one-device run on an
+    8-device host keeps its parameters on that device."""
+    run_distributed(PRELUDE + """
+from repro.launch.mesh import make_host_mesh
+from repro.launch.train import TrainerConfig, train
+from repro.serving import ServeConfig, build_engine
+assert make_host_mesh().devices.size == 8
+two = make_host_mesh(devices=jax.devices()[2:4])
+assert [d.id for d in two.devices.flat] == [2, 3]
+dev = jax.devices()[3]
+def placed(tree):
+    return {d.id for leaf in jax.tree_util.tree_leaves(tree)
+            for d in leaf.devices()}
+eng = build_engine(ServeConfig(num_slots=2, prefill_buckets=(8,),
+                               max_new_tokens=2), devices=[dev])
+assert placed(eng.backend.params) == {3}, placed(eng.backend.params)
+uid = eng.submit([5, 9, 2])
+for _ in eng.run(max_steps=8):
+    pass
+assert len(eng.result(uid).tokens) == 2
+out = train(TrainerConfig(steps=1, global_batch=2, seq_len=16, log_every=1),
+            devices=[dev])
+assert placed(out["params"]) == {3}, placed(out["params"])
+assert out["step_compiles"] == 1
 print("ok")
 """)

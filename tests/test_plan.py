@@ -410,6 +410,45 @@ def test_default_interpret_env_override(monkeypatch):
     assert ops._default_interpret() == (jax.default_backend() != "tpu")
 
 
+def test_default_interpret_refused_on_tpu(monkeypatch):
+    """On a TPU backend kernels run compiled: asking for interpret mode is
+    an error, never a silent switch to the interpreter."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+    assert ops._default_interpret() is False
+    monkeypatch.setenv("REPRO_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="interpret"):
+        ops._default_interpret()
+
+
+@pytest.mark.parametrize("keys,feasible", [(4096, True), (32768, False)])
+def test_kernel_tier_fast_memory_bound(monkeypatch, keys, feasible):
+    """On TPU the kernel tier is feasible only while its one-hot mask and
+    resident output block fit the chip's scoped fast memory: a key space
+    that does not fit goes to segment-ops at PLAN time, with the reason in
+    describe(), and a forced layout='kernel' raises."""
+    from repro.core import plan as plan_mod
+
+    monkeypatch.setattr(plan_mod.jax, "default_backend", lambda: "tpu")
+    n = 1 << 22
+    vals = jax.ShapeDtypeStruct((n, 1), jnp.float32)
+    segs = jax.ShapeDtypeStruct((n,), jnp.int32)
+    p = plan_fold(monoids.sum_, vals, segment_ids=segs, num_segments=keys)
+    if feasible:
+        assert p.local_tier.kind == "kernel"
+        assert p.kernel_infeasible is None
+        assert "kernel infeasible" not in p.describe()
+        return
+    assert p.local_tier.kind == "segment_ops"
+    assert "fast memory" in p.kernel_infeasible
+    assert f"kernel infeasible: {keys} keys" in p.describe()
+    with pytest.raises(ValueError, match="fast memory"):
+        plan_fold(monoids.sum_, vals, segment_ids=segs, num_segments=keys,
+                  layout="kernel")
+
+
 def test_plan_reports_tiers_and_collective_bytes():
     """plan_fold is a pure cost model: ShapeDtypeStructs in, tier chain and
     predicted wire bytes out — ICI axes reduced before the DCN pod axis."""

@@ -281,6 +281,11 @@ def test_arrival_trace_bit_identical_to_solo(trace):
 # real model substrate (smoke config) + deprecated shim
 # ---------------------------------------------------------------------------
 
+# logprob sums of one request decoded in batches of different shapes: a few
+# float32 ulps of a sum of a handful of per-token logprobs
+LOGPROB_RTOL = 1e-5
+
+
 class TestRealModelServing:
     @pytest.fixture(scope="class")
     def engine_factory(self):
@@ -318,7 +323,11 @@ class TestRealModelServing:
         for p, uid in zip(prompts, uids):
             ref, got = self.solo(solo_engine, p), eng.result(uid)
             assert got.tokens == ref.tokens
-            assert got.logprob_sum == ref.logprob_sum   # bitwise
+            # a 2-slot and a 1-slot step are different programs: XLA may
+            # order the float reductions by batch shape, so the sums agree
+            # to float32 rounding, not bit for bit
+            assert got.logprob_sum == pytest.approx(ref.logprob_sum,
+                                                    rel=LOGPROB_RTOL)
             assert got.stopped == ref.stopped
 
     def test_prefix_hit_bit_identical_to_cold(self, engine_factory):
@@ -360,7 +369,9 @@ class TestRealModelServing:
         for i, p in enumerate(prompts):
             ref = self.solo(solo_engine, p)
             assert res.tokens[i, : len(ref.tokens)].tolist() == ref.tokens
-            assert res.metrics["logprob_sum"][i] == np.float32(ref.logprob_sum)
+            # different batch shapes: equal to float32 rounding (see above)
+            assert res.metrics["logprob_sum"][i] == pytest.approx(
+                ref.logprob_sum, rel=LOGPROB_RTOL)
             assert res.metrics["tokens"][i] == len(ref.tokens)
             assert res.metrics["stopped"][i] == ref.stopped
         assert res.decode_steps == eng.stats.steps

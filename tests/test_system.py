@@ -104,3 +104,51 @@ def test_microbatched_train_step_matches_full():
         res[name] = {k: float(v) for k, v in metrics.items()}
     assert abs(res["full"]["loss"] - res["micro"]["loss"]) < 5e-3
     assert res["full"]["tokens"] == res["micro"]["tokens"]
+
+
+def test_train_step_compiles_once(short_run):
+    _, out = short_run
+    assert out["step_compiles"] == 1
+
+
+def test_compile_cache_in_checkout_by_default(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the entry points keep the cache at
+    a fixed, git-ignored path in the checkout."""
+    import pathlib
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
+def test_compile_cache_env_wins(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, nothing is set in code and JAX
+    writes its cache there."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(root, "src"))
+    code = """
+import os, jax, jax.numpy as jnp
+from repro.launch.compile_cache import use_compile_cache
+assert use_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
+jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+"""
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert any(name.startswith("jit__lambda") for name in os.listdir(tmp_path))
