@@ -113,6 +113,8 @@ class BatcherStats:
     flushed_batches: int = 0
     flushed_requests: int = 0
     waited_flushes: int = 0     # flushes fired by the max-wait policy
+    taken: int = 0              # requests popped by take()
+    queue_wait_s: float = 0.0   # their summed wait from submit to take
 
     def fill_rate(self, max_batch_size: int) -> float:
         """Mean slot occupancy of flushed batches (1.0 = always full)."""
@@ -166,18 +168,22 @@ class RequestBatcher:
         """True when a flush policy fires: full batch, or oldest waited out."""
         return any(self._policy())
 
-    def take(self, n: int) -> Tuple[Request, ...]:
+    def take(self, n: int, *,
+             now: Optional[float] = None) -> Tuple[Request, ...]:
         """Pop up to ``n`` oldest pending requests (continuous-batching
         admission: one request per freed slot, arrival order preserved).
 
         Unlike :meth:`flush` this never waits on a policy — a free slot is
-        capacity going idle, so admission is immediate.
+        capacity going idle, so admission is immediate.  ``now`` is the
+        clock reading that ends their wait (read here when not given).
         """
         if n < 0:
             raise ValueError("n must be >= 0")
+        now = self._clock() if now is None else now
         take = min(n, len(self._pending))
         reqs = tuple(self._pending.popleft() for _ in range(take))
-        self.stats.flushed_requests += take
+        self.stats.taken += take
+        self.stats.queue_wait_s += sum(now - r.arrival_s for r in reqs)
         return reqs
 
     def flush(self, *, force: bool = False) -> Optional[DecodeBatch]:

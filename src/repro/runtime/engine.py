@@ -39,6 +39,11 @@ positions (``init_cache(pos_per_slot=True)``): each row writes and masks
 its KV at its own position, so a reused slot's computation is bit-identical
 to the same request decoded alone.
 
+Each layer boundary (admission, queue wait, prefix lookup/insert, slab
+assembly and upload, prefill, gather, slot write, decode, retirement) is a
+span of :mod:`repro.runtime.spans`, kept only while that recorder runs;
+the matching counts are in :class:`EngineStats`.
+
 The engine is model-agnostic: it drives an :class:`EngineBackend` (a
 traceable decode function + cache constructor), so the whole slot/admission
 machinery is testable without a model.  ``repro.launch.serve.build_engine``
@@ -59,6 +64,7 @@ import numpy as np
 from ..core import monoids
 from ..core.plan import Plan, execute_fold, plan_fold
 from ..models.attention import cache_span_update
+from . import spans
 from .batcher import Request, RequestBatcher
 from .prefix_cache import PrefixCache, PrefixCacheConfig, PrefixHit
 
@@ -331,6 +337,9 @@ class EngineStats:
     generated_tokens: int = 0
     prefill_calls: int = 0        # prefill program invocations (k >= 1 each)
     batched_admissions: int = 0   # admissions that shared a k > 1 prefill
+    slot_steps: int = 0           # occupied slots summed over decode steps
+    prefix_upload_bytes: int = 0      # prefix KV slabs sent to the device
+    prefix_download_bytes: int = 0    # prefix KV rows fetched for the trie
 
 
 @dataclasses.dataclass
@@ -643,43 +652,53 @@ class ContinuousEngine:
         free = [i for i, s in enumerate(self._slots) if s is None]
         if not free:
             return
-        reqs = self.queue.take(len(free))
+        # one reading ends each request's wait in the queue and opens the
+        # admission, so the two spans add up to the request's TTFT
+        taken = self._clock()
+        reqs = self.queue.take(len(free), now=taken)
         if not reqs:
             return
+        for req in reqs:
+            spans.add("engine.queued", req.arrival_s, taken, uid=req.uid)
         cfg = self.config
-        jobs: List[_AdmitJob] = []
-        for req, slot in zip(reqs, free):
-            plen = len(req.prompt)
-            # the trie walk: requests prefill only their uncached suffix,
-            # and the prefill bucket is chosen on SUFFIX length
-            hit = self.prefix.lookup(req.prompt) \
-                if self.prefix is not None else None
-            hit_len = hit.length if hit is not None else 0
-            jobs.append(_AdmitJob(
-                req=req, slot=slot, plen=plen, hit=hit,
-                seed=self._seeds.pop(req.uid, req.uid),
-                bucket=cfg.bucket_for(plen - hit_len)))
+        with spans.span("engine.admit", start=taken,
+                        uids=[r.uid for r in reqs]) as admit:
+            jobs: List[_AdmitJob] = []
+            for req, slot in zip(reqs, free):
+                plen = len(req.prompt)
+                # the trie walk: requests prefill only their uncached
+                # suffix, and the prefill bucket is chosen on SUFFIX length
+                hit = None
+                if self.prefix is not None:
+                    with spans.span("prefix.lookup", uid=req.uid):
+                        hit = self.prefix.lookup(req.prompt)
+                hit_len = hit.length if hit is not None else 0
+                jobs.append(_AdmitJob(
+                    req=req, slot=slot, plen=plen, hit=hit,
+                    seed=self._seeds.pop(req.uid, req.uid),
+                    bucket=cfg.bucket_for(plen - hit_len)))
 
-        # group same-bucket admissions into shared (k, bucket) prefill
-        # programs, k drawn from the declared power-of-two ladder
-        groups: Dict[int, List[_AdmitJob]] = {}
-        order: List[int] = []
-        for job in jobs:
-            if job.bucket not in groups:
-                groups[job.bucket] = []
-                order.append(job.bucket)
-            groups[job.bucket].append(job)
-        ladder = cfg.prefill_k_ladder
-        for b in order:
-            group = groups[b]
-            while group:
-                k = max(x for x in ladder if x <= len(group))
-                self._admit_chunk(group[:k], b)
-                group = group[k:]
+            # group same-bucket admissions into shared (k, bucket) prefill
+            # programs, k drawn from the declared power-of-two ladder
+            groups: Dict[int, List[_AdmitJob]] = {}
+            order: List[int] = []
+            for job in jobs:
+                if job.bucket not in groups:
+                    groups[job.bucket] = []
+                    order.append(job.bucket)
+                groups[job.bucket].append(job)
+            ladder = cfg.prefill_k_ladder
+            for b in order:
+                group = groups[b]
+                while group:
+                    k = max(x for x in ladder if x <= len(group))
+                    self._admit_chunk(group[:k], b)
+                    group = group[k:]
+            # the first tokens' time closes the admission
+            now = admit.end_at(self._clock())
 
         # stream in arrival order regardless of chunk grouping: the
         # admission accounting ("cache") event, then the first token
-        now = self._clock()
         retire: List[int] = []
         for job in jobs:
             st = _SlotState(uid=job.req.uid, user=job.req.user,
@@ -715,56 +734,73 @@ class ContinuousEngine:
         """Prefill up to k same-bucket requests in ONE compiled program,
         scatter their (prefix-loaded) caches into the rolling cache, and
         feed each request's first-slab KV back into the trie."""
-        cfg = self.config
-        k = len(jobs)
-        toks = np.full((k, bucket), cfg.pad_id, np.int32)
-        suffix_lens = np.zeros((k,), np.int32)
-        plens = np.zeros((k,), np.int32)
-        seeds = np.zeros((k,), np.int32)
-        prefix_lens = np.zeros((k,), np.int32)
-        for r, job in enumerate(jobs):
-            hit_len = job.hit.length if job.hit is not None else 0
-            suffix = job.req.prompt[hit_len:]
-            toks[r, :len(suffix)] = suffix
-            suffix_lens[r] = len(suffix)
-            plens[r] = job.plen
-            seeds[r] = job.seed
-            prefix_lens[r] = hit_len
-        cachek = self.backend.init_cache(k, True)
-        fn = self._prefill_fns[(k, bucket)]
-        if self._slab:
-            leaves = [jnp.asarray(a) for a in self._assemble_prefix(jobs, k)]
-            cachek, sampled, rows = fn(
-                self.backend.params, cachek, jnp.asarray(toks),
-                jnp.asarray(suffix_lens), jnp.asarray(seeds), leaves,
-                jnp.asarray(prefix_lens))
-        else:
-            cachek, sampled, rows = fn(
-                self.backend.params, cachek, jnp.asarray(toks),
-                jnp.asarray(suffix_lens), jnp.asarray(seeds))
-        # gather BEFORE the (donating) slot write: the first `slab` KV rows
-        # of every admitted request, host-side, become trie payloads
-        gathered = None
-        if self.prefix is not None:
-            gathered = jax.device_get(self._gather_fns[k](cachek))
-        slots = np.asarray([j.slot for j in jobs], np.int32)
-        self._cache, self._table = self._write_fns[k](
-            self._cache, cachek, jnp.asarray(slots), jnp.asarray(plens),
-            self._table, rows)
-        sampled_np = np.asarray(jax.device_get(sampled))
-        for r, job in enumerate(jobs):
-            job.first = int(sampled_np[r])
-        if gathered is not None:
-            g_leaves = jax.tree_util.tree_leaves(gathered)
-            max_blocks = self._slab // cfg.prefix_block
+        with spans.span("engine.prefill_chunk", k=len(jobs), bucket=bucket,
+                        uids=[j.req.uid for j in jobs]):
+            cfg = self.config
+            k = len(jobs)
+            toks = np.full((k, bucket), cfg.pad_id, np.int32)
+            suffix_lens = np.zeros((k,), np.int32)
+            plens = np.zeros((k,), np.int32)
+            seeds = np.zeros((k,), np.int32)
+            prefix_lens = np.zeros((k,), np.int32)
             for r, job in enumerate(jobs):
-                self.prefix.insert(
-                    job.req.prompt,
-                    lambda i, r=r: self._slice_block(g_leaves, r, i),
-                    max_blocks=max_blocks)
-        self.stats.prefill_calls += 1
-        if k > 1:
-            self.stats.batched_admissions += k
+                hit_len = job.hit.length if job.hit is not None else 0
+                suffix = job.req.prompt[hit_len:]
+                toks[r, :len(suffix)] = suffix
+                suffix_lens[r] = len(suffix)
+                plens[r] = job.plen
+                seeds[r] = job.seed
+                prefix_lens[r] = hit_len
+            cachek = self.backend.init_cache(k, True)
+            fn = self._prefill_fns[(k, bucket)]
+            if self._slab:
+                with spans.span("engine.assemble_prefix"):
+                    slabs = self._assemble_prefix(jobs, k)
+                with spans.span("engine.prefix_upload"):
+                    leaves = [jnp.asarray(a) for a in slabs]
+                self.stats.prefix_upload_bytes += sum(a.nbytes
+                                                      for a in slabs)
+                with spans.span("engine.prefill"):
+                    cachek, sampled, rows = fn(
+                        self.backend.params, cachek, jnp.asarray(toks),
+                        jnp.asarray(suffix_lens), jnp.asarray(seeds),
+                        leaves, jnp.asarray(prefix_lens))
+            else:
+                with spans.span("engine.prefill"):
+                    cachek, sampled, rows = fn(
+                        self.backend.params, cachek, jnp.asarray(toks),
+                        jnp.asarray(suffix_lens), jnp.asarray(seeds))
+            # gather BEFORE the (donating) slot write: the first `slab` KV
+            # rows of every admitted request, host-side, become trie
+            # payloads
+            g_leaves = None
+            if self.prefix is not None:
+                with spans.span("engine.wait_gather", wait=True):
+                    g_leaves = jax.tree_util.tree_leaves(
+                        jax.device_get(self._gather_fns[k](cachek)))
+                self.stats.prefix_download_bytes += sum(a.nbytes
+                                                        for a in g_leaves)
+            with spans.span("engine.slot_write"):
+                slots = np.asarray([j.slot for j in jobs], np.int32)
+                self._cache, self._table = self._write_fns[k](
+                    self._cache, cachek, jnp.asarray(slots),
+                    jnp.asarray(plens), self._table, rows)
+            with spans.span("engine.wait_first_tokens", wait=True):
+                sampled_np = np.asarray(jax.device_get(sampled))
+            for r, job in enumerate(jobs):
+                job.first = int(sampled_np[r])
+            if g_leaves is not None:
+                max_blocks = self._slab // cfg.prefix_block
+                for r, job in enumerate(jobs):
+                    with spans.span("prefix.insert", uid=job.req.uid):
+                        self.prefix.insert(
+                            job.req.prompt,
+                            lambda i, r=r: self._slice_block(g_leaves, r,
+                                                             i),
+                            max_blocks=max_blocks)
+            self.stats.prefill_calls += 1
+            if k > 1:
+                self.stats.batched_admissions += k
 
     def _assemble_prefix(self, jobs: List[_AdmitJob],
                          k: int) -> List[np.ndarray]:
@@ -806,57 +842,72 @@ class ContinuousEngine:
 
     def _retire(self, slots: List[int], events: List[StreamEvent],
                 now: float) -> None:
-        table = np.asarray(jax.device_get(self._table))
-        for slot in slots:
-            st = self._slots[slot]
-            res = RequestResult(
-                uid=st.uid, slot=slot, prompt_len=st.prompt_len,
-                bucket=st.bucket, user=st.user, tokens=list(st.tokens),
-                logprob_sum=float(table[slot, 0]),
-                stopped=bool(table[slot, 2] > 0),
-                stop_step=self._step_count,
-                ttft_s=st.ttft_s, latency_s=now - st.arrival_s)
-            self.results[st.uid] = res
-            self._slots[slot] = None
-            self.stats.completed += 1
-            events.append(StreamEvent(uid=st.uid, kind="done", slot=slot,
-                                      step=self._step_count, time_s=now,
-                                      user=st.user, result=res))
+        with spans.span("engine.retire",
+                        uids=[self._slots[s].uid for s in slots]):
+            with spans.span("engine.wait_table", wait=True):
+                table = np.asarray(jax.device_get(self._table))
+            for slot in slots:
+                st = self._slots[slot]
+                res = RequestResult(
+                    uid=st.uid, slot=slot, prompt_len=st.prompt_len,
+                    bucket=st.bucket, user=st.user, tokens=list(st.tokens),
+                    logprob_sum=float(table[slot, 0]),
+                    stopped=bool(table[slot, 2] > 0),
+                    stop_step=self._step_count,
+                    ttft_s=st.ttft_s, latency_s=now - st.arrival_s)
+                self.results[st.uid] = res
+                self._slots[slot] = None
+                self.stats.completed += 1
+                events.append(StreamEvent(uid=st.uid, kind="done", slot=slot,
+                                          step=self._step_count, time_s=now,
+                                          user=st.user, result=res))
 
     # -- the rolling decode step --------------------------------------------
 
     def step(self) -> List[StreamEvent]:
         """Admit waiting requests into free slots, then advance the whole
         rolling population one token.  Returns the streamed events."""
-        events: List[StreamEvent] = []
-        self._admit(events)
-        if self.prefix is not None:
-            # ONE keyed stats fold per engine step carries every cache
-            # event this step produced (hits + inserts)
-            self.prefix.flush_stats()
-        S = self.config.num_slots
-        occupied = [i for i, s in enumerate(self._slots) if s is not None]
-        if not occupied:
+        with spans.span("engine.step"):
+            events: List[StreamEvent] = []
+            self._admit(events)
+            if self.prefix is not None:
+                # ONE keyed stats fold per engine step carries every cache
+                # event this step produced (hits + inserts)
+                with spans.span("prefix.flush_stats"):
+                    self.prefix.flush_stats()
+            occupied = [i for i, s in enumerate(self._slots)
+                        if s is not None]
+            if occupied:
+                with spans.span("engine.decode", slots=len(occupied)):
+                    self._decode(occupied, events)
             return self._dispatch(events)
 
-        cur = np.zeros((S, 1), np.int32)
-        active = np.zeros((S,), bool)
-        seeds = np.zeros((S,), np.int32)
-        tok_idx = np.zeros((S,), np.int32)
-        for i in occupied:
-            st = self._slots[i]
-            cur[i, 0] = st.cur
-            active[i] = True
-            seeds[i] = st.seed
-            tok_idx[i] = st.n_gen
-        self._cache, sampled, self._table = self._step_fn(
-            self.backend.params, self._cache, jnp.asarray(cur),
-            jnp.asarray(active), jnp.asarray(seeds), jnp.asarray(tok_idx),
-            self._table)
+    def _decode(self, occupied: List[int],
+                events: List[StreamEvent]) -> None:
+        """One token for every occupied slot; retire those that finish."""
+        S = self.config.num_slots
+        with spans.span("engine.decode_inputs"):
+            cur = np.zeros((S, 1), np.int32)
+            active = np.zeros((S,), bool)
+            seeds = np.zeros((S,), np.int32)
+            tok_idx = np.zeros((S,), np.int32)
+            for i in occupied:
+                st = self._slots[i]
+                cur[i, 0] = st.cur
+                active[i] = True
+                seeds[i] = st.seed
+                tok_idx[i] = st.n_gen
+        with spans.span("engine.decode_dispatch"):
+            self._cache, sampled, self._table = self._step_fn(
+                self.backend.params, self._cache, jnp.asarray(cur),
+                jnp.asarray(active), jnp.asarray(seeds),
+                jnp.asarray(tok_idx), self._table)
         self._step_count += 1
         self.stats.steps += 1
+        self.stats.slot_steps += len(occupied)
 
-        sampled_np = np.asarray(jax.device_get(sampled))
+        with spans.span("engine.wait_tokens", wait=True):
+            sampled_np = np.asarray(jax.device_get(sampled))
         now = self._clock()
         retired = []
         for i in occupied:
@@ -873,12 +924,12 @@ class ContinuousEngine:
                 retired.append(i)
         if retired:
             self._retire(retired, events, now)
-        return self._dispatch(events)
 
     def _dispatch(self, events: List[StreamEvent]) -> List[StreamEvent]:
-        for ev in events:
-            for consumer in self._consumers:
-                consumer(ev)
+        with spans.span("engine.dispatch_events"):
+            for ev in events:
+                for consumer in self._consumers:
+                    consumer(ev)
         return events
 
     def run(self, *, max_steps: Optional[int] = None) -> Iterator[StreamEvent]:

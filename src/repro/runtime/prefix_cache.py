@@ -39,6 +39,7 @@ import numpy as np
 
 from ..core import monoids
 from ..core.plan import execute_fold
+from . import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,20 +336,25 @@ class PrefixCache:
         return done
 
     def _evict_one(self, protect) -> bool:
-        # pending hit events move scores: fold them BEFORE choosing a victim
-        # (also: no pending row may reference the id we are about to free)
-        self.flush_stats()
-        candidates = [nd for nd in self._nodes.values()
-                      if not nd.children and nd.node_id not in protect]
-        if not candidates:
-            return False
-        scores = self.scores(float(self._clock()))
-        victim = min(candidates,
-                     key=lambda nd: (float(scores[nd.node_id]), nd.node_id))
-        del victim.parent.children[victim.key]
-        del self._nodes[victim.node_id]
-        self._bytes -= victim.nbytes
-        self._table = self._clear_fn(self._table, jnp.int32(victim.node_id))
-        self._free.append(victim.node_id)
-        self.stats.evictions += 1
-        return True
+        with spans.span("prefix.evict"):
+            # pending hit events move scores: fold them BEFORE choosing a
+            # victim (also: no pending row may reference the id we are
+            # about to free)
+            self.flush_stats()
+            candidates = [nd for nd in self._nodes.values()
+                          if not nd.children and nd.node_id not in protect]
+            if not candidates:
+                return False
+            with spans.span("prefix.wait_scores", wait=True):
+                scores = self.scores(float(self._clock()))
+            victim = min(candidates,
+                         key=lambda nd: (float(scores[nd.node_id]),
+                                         nd.node_id))
+            del victim.parent.children[victim.key]
+            del self._nodes[victim.node_id]
+            self._bytes -= victim.nbytes
+            self._table = self._clear_fn(self._table,
+                                         jnp.int32(victim.node_id))
+            self._free.append(victim.node_id)
+            self.stats.evictions += 1
+            return True
