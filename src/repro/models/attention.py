@@ -232,20 +232,27 @@ def _chunked_attention(cfg, q, k, v, q_pos, k_pos, *, window, chunk_size):
 
 
 # ---------------------------------------------------------------------------
-# decode attention (1 new token against a cache)
+# decode attention (L new tokens against a cache)
 # ---------------------------------------------------------------------------
 
-def decode_positions(pos: jnp.ndarray, batch: int) -> jnp.ndarray:
-    """(B, 1) query positions from a scalar OR per-row ``pos``.
+def decode_positions(pos: jnp.ndarray, batch: int,
+                     length: int = 1) -> jnp.ndarray:
+    """(B, L) query positions ``pos + arange(L)`` from a scalar OR per-row
+    ``pos``.
 
     Scalar ``pos`` is the classic aligned-batch decode (every row at the
     same position).  A ``(B,)`` ``pos`` is the continuous-batching case:
     each request slot carries its own position, so a freed slot can restart
-    at 0 mid-decode while its neighbours keep generating.
+    at 0 mid-decode while its neighbours keep generating.  ``length > 1``
+    is an extend: L consecutive tokens per row, as a prefill writes them.
     """
     if jnp.ndim(pos) == 1:
-        return pos[:, None].astype(jnp.int32)
-    return jnp.full((batch, 1), pos, jnp.int32)
+        first = pos[:, None].astype(jnp.int32)
+    else:
+        first = jnp.full((batch, 1), pos, jnp.int32)
+    if length == 1:
+        return first
+    return first + jnp.arange(length, dtype=jnp.int32)
 
 
 def cache_span_update(cache: jnp.ndarray, new: jnp.ndarray,
@@ -254,29 +261,43 @@ def cache_span_update(cache: jnp.ndarray, new: jnp.ndarray,
     positions.
 
     cache: (..., S, ...) with the sequence axis at ``seq_axis``; new is the
-    same shape with span length L in place of S; pos: () or (B,) start
+    same shape with span length L <= S in place of S; pos: () or (B,) start
     positions (the batch axis is ``seq_axis - 1`` for the per-row form).
-    The single-row case (L == 1) is the classic decode write; the span case
-    is the prefix-cache scatter — a cached KV prefix lands in the slot cache
-    in one write instead of L decode steps.
+    The single-row case (L == 1) is the classic decode write.  For L > 1
+    (an extend) rows that would land at or past S are DROPPED: a bare
+    ``dynamic_update_slice`` would clamp the start and shift every row of
+    the span, real ones included, to the wrong positions.
     """
+    L, S = new.shape[seq_axis], cache.shape[seq_axis]
+
+    def write(c, n, i, axis):
+        if L == 1:
+            return jax.lax.dynamic_update_slice_in_dim(c, n, i, axis=axis)
+        start = jnp.minimum(i, S - L)
+        shift = i - start                      # rows of the span past S
+        old = jax.lax.dynamic_slice_in_dim(c, start, L, axis=axis)
+        moved = jnp.roll(n, shift, axis=axis)  # row j <- new row j - shift
+        keep = jnp.arange(L) >= shift
+        keep = keep.reshape((L,) + (1,) * (n.ndim - axis - 1))
+        return jax.lax.dynamic_update_slice_in_dim(
+            c, jnp.where(keep, moved, old), start, axis=axis)
+
     if jnp.ndim(pos) == 1:
         return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-                c, n, i, axis=seq_axis - 1),
+            lambda c, n, i: write(c, n, i, seq_axis - 1),
             in_axes=(seq_axis - 1, seq_axis - 1, 0),
             out_axes=seq_axis - 1)(cache, new, pos)
-    return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=seq_axis)
+    return write(cache, new, pos, seq_axis)
 
 
 def cache_row_update(cache: jnp.ndarray, new: jnp.ndarray,
                      pos: jnp.ndarray) -> jnp.ndarray:
-    """Write one new-token slice into a cache at scalar or per-row positions.
+    """Write new-token rows into a cache at scalar or per-row positions.
 
-    cache: (B, S, ...); new: (B, 1, ...); pos: () or (B,).  The per-row form
+    cache: (B, S, ...); new: (B, L, ...); pos: () or (B,).  The per-row form
     is a vmapped dynamic_update_slice — each request slot writes at its own
-    position (continuous batching).  One-row special case of
-    :func:`cache_span_update`.
+    position (continuous batching).  :func:`cache_span_update` on the
+    sequence axis.
     """
     return cache_span_update(cache, new, pos, seq_axis=1)
 
@@ -285,11 +306,13 @@ def attention_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                      cache: Tuple[jnp.ndarray, jnp.ndarray], pos: jnp.ndarray,
                      *, window: Optional[int] = None,
                      kv_shards: int = 1) -> Tuple[jnp.ndarray, Tuple]:
-    """One decode step. x: (B, 1, D); cache: (k, v) each (B, S, KV, hd);
+    """One decode step. x: (B, L, D); cache: (k, v) each (B, S, KV, hd);
     pos: () current position (tokens 0..pos-1 are valid in the cache), or
     (B,) per-slot positions for a continuously-batched cache — each row
     writes and masks at its own position, so rows stay independent and a
-    reused slot's computation is identical to a fresh batch's.
+    reused slot's computation is identical to a fresh batch's.  L > 1 is an
+    extend: the L tokens at pos..pos+L-1 are written and attend causally
+    over the cache in one pass (scores (B, H, L, S)).
 
     kv_shards > 1 requests flash-decoding: the KV cache's sequence axis is
     sharded over the 'model' mesh axis and partial attn_states are merged
@@ -297,7 +320,7 @@ def attention_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     """
     kcache, vcache = cache
     B, S = kcache.shape[0], kcache.shape[1]
-    positions = decode_positions(pos, B)
+    positions = decode_positions(pos, B, x.shape[1])
     q, k_new, v_new = _project_qkv(p, cfg, x, positions)
     kcache = cache_row_update(kcache, k_new, pos)
     vcache = cache_row_update(vcache, v_new, pos)
@@ -306,11 +329,11 @@ def attention_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
 
     k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    scores = _gqa_scores(q, kcache, scale)                      # (B,H,1,S)
+    scores = _gqa_scores(q, kcache, scale)                      # (B,H,L,S)
     keep = _causal_mask(positions, k_pos, window)[:, None]
     scores = jnp.where(keep, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o = _gqa_values(w, vcache)                                  # (B,1,H,hd)
+    o = _gqa_values(w, vcache)                                  # (B,L,H,hd)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     return shd.act(out, ("batch", None, "embed")), (kcache, vcache)
 
@@ -536,19 +559,20 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
       o        = o_latent @ wv_b  then wo     (absorb the v up-projection)
 
     The cache is (kvl + dr) floats/token shared across ALL heads — the MLA
-    memory-term win reported in the roofline table.
+    memory-term win reported in the roofline table.  x may hold L > 1
+    tokens (an extend), masked causally as in :func:`attention_decode`.
     """
     c_cache, r_cache = cache
     B, S = c_cache.shape[0], c_cache.shape[1]
-    positions = decode_positions(pos, B)
-    q_nope, q_rope = _mla_q(p, cfg, x, positions)               # (B,1,H,*)
+    positions = decode_positions(pos, B, x.shape[1])
+    q_nope, q_rope = _mla_q(p, cfg, x, positions)               # (B,L,H,*)
     c_new, r_new = _mla_latent(p, cfg, x, positions)
     c_cache = cache_row_update(c_cache, c_new, pos)
     r_cache = cache_row_update(r_cache, r_new, pos)
     c_cache = shd.act(c_cache, ("batch", "kv_seq", None))
     r_cache = shd.act(r_cache, ("batch", "kv_seq", None))
 
-    # absorb wk_b into q: (B,1,H,dn) x (kvl,H,dn) -> (B,1,H,kvl)
+    # absorb wk_b into q: (B,L,H,dn) x (kvl,H,dn) -> (B,L,H,kvl)
     q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, p["wk_b"].astype(x.dtype))
     s_lat = jnp.einsum("bqhl,bsl->bhqs", q_lat, c_cache,
                        preferred_element_type=jnp.float32)
@@ -560,7 +584,7 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     keep = _causal_mask(positions, k_pos)[:, None]
     scores = jnp.where(keep, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bhqs,bsl->bqhl", w, c_cache)            # (B,1,H,kvl)
+    o_lat = jnp.einsum("bhqs,bsl->bqhl", w, c_cache)            # (B,L,H,kvl)
     o = jnp.einsum("bqhl,lhv->bqhv", o_lat, p["wv_b"].astype(x.dtype))
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     return shd.act(out, ("batch", None, "embed")), (c_cache, r_cache)

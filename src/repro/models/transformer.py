@@ -443,9 +443,34 @@ def init_cache(params: Pytree, cfg: ModelConfig, batch: int, max_seq: int, *,
     return cache
 
 
+def _extends_in_parallel(ltype: str, ctx: RunCtx) -> bool:
+    """Whether a layer of this kind takes L > 1 new tokens in one pass.
+
+    Attention and MLA over a position-indexed cache write L rows and mask
+    causally by position; cross-attention has no mask and no cache write.
+    Recurrent state (mamba, mLSTM, sLSTM) and the sequence-sharded flash
+    decode, which writes one row on its owning shard, take one token at a
+    time."""
+    if ltype in ("attn", "local"):
+        return not (ctx.decode_impl == "flash" and ctx.mesh is not None)
+    return ltype == "xattn"
+
+
 def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
                   x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
                   ctx: RunCtx):
+    L = x.shape[1]
+    if L > 1 and not _extends_in_parallel(ltype, ctx):
+        # scan the L columns one token at a time through this layer
+        def column(c, inp):
+            xj, j = inp
+            y, c = _decode_layer(p, cfg, ltype, ftype, xj[:, None], c,
+                                 pos + j, ctx)
+            return c, y[:, 0]
+
+        cache, ys = jax.lax.scan(
+            column, cache, (jnp.swapaxes(x, 0, 1), jnp.arange(L)))
+        return jnp.swapaxes(ys, 0, 1), cache
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if ltype in ("attn", "local"):
         window = cfg.sliding_window if ltype == "local" else None
@@ -486,14 +511,24 @@ def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
 def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
                 tokens: jnp.ndarray, *, ctx: RunCtx = RunCtx()
                 ) -> Tuple[jnp.ndarray, Pytree]:
-    """One serving step: (B, 1) new tokens -> (B, 1, V) logits + new caches."""
-    B = tokens.shape[0]
+    """One serving step: (B, L) new tokens -> (B, 1, V) logits of the LAST
+    column + new caches, ``pos`` advanced by L.
+
+    L == 1 is the decode step.  L > 1 is an extend (a prefill chunk): token
+    j sits at position ``pos + j`` (scalar or per-row ``pos``), attention
+    and MLA layers write the L cache rows and attend causally in one
+    parallel pass, and layers that cannot (see :func:`_extends_in_parallel`)
+    scan the L columns.  Only the last column is unembedded, so an extend
+    never holds (B, L, V) logits.  Rows that would fall past the cache's
+    end are dropped (:func:`attention.cache_span_update`).
+    """
+    B, L = tokens.shape
     pos = cache["pos"]
     x = embed_lookup(params["embed"], tokens).astype(cfg.dtype)
     if cfg.scale_embed:
         x = x * math.sqrt(cfg.d_model)
     x = shd.act(x, ("batch", None, "embed"))
-    new_cache: Dict[str, Any] = {"pos": pos + 1}
+    new_cache: Dict[str, Any] = {"pos": pos + L}
 
     prelude, period_slots, remainder = _layer_plan(cfg)
     for i, (lt, ft) in enumerate(prelude):
@@ -517,6 +552,8 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
         x, new_cache[f"rem_{i}"] = _decode_layer(
             params[f"rem_{i}"], cfg, lt, ft, x, cache[f"rem_{i}"], pos, ctx)
 
+    if L > 1:
+        x = x[:, -1:]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, x)
     return logits, new_cache

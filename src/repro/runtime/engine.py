@@ -17,11 +17,14 @@ Compilation is bucketed so slot churn never recompiles anything:
 * ONE decode-step program at ``(num_slots, 1)`` — model forward + per-row
   sampling + the keyed masked metrics fold, jitted together.
 * ONE prefill program per ``(k, bucket)`` pair — up to k same-bucket
-  admissions ``lax.scan`` the decode step together over their prompts
-  padded to the bucket, against a fresh k-row cache whose first ``slab``
-  rows were scattered from the radix prefix cache
+  admissions prefill together, against a fresh k-row cache whose first
+  ``slab`` rows were scattered from the radix prefix cache
   (``runtime/prefix_cache.py``) so only the uncached SUFFIX is computed
-  (buckets are chosen on suffix length).
+  (buckets are chosen on suffix length).  A positional backend extends the
+  cache by each suffix (minus its last token, padded to the bucket) in ONE
+  parallel decode call, then decodes the last tokens for the first-token
+  logits; any other backend scans the one-token decode over the bucket
+  (``lax.scan``).
 * ONE slot-write program per k — scatter the prefilled k-row cache into
   the rolling cache at the freed slots (resetting their metrics rows) —
   and, with the prefix cache on, ONE gather program per k that slices the
@@ -63,7 +66,6 @@ import numpy as np
 
 from ..core import monoids
 from ..core.plan import Plan, execute_fold, plan_fold
-from ..models.attention import cache_span_update
 from . import spans
 from .batcher import Request, RequestBatcher
 from .prefix_cache import PrefixCache, PrefixCacheConfig, PrefixHit
@@ -297,7 +299,13 @@ class EngineBackend:
     ``decode(params, cache, cur)`` must be *traceable* (the engine jits it,
     fused with sampling and the metrics fold) and row-independent: row b of
     the outputs depends only on row b of ``cache``/``cur``.  ``cur`` is
-    ``(B, 1)`` int32; it returns ``((B, V) float32 logits, new cache)``.
+    ``(B, L)`` int32, token j of row b at position ``pos[b] + j``; it
+    returns ``((B, V) float32 logits of the LAST column, new cache)`` with
+    ``pos`` advanced by L.  Decode steps pass L = 1.  With
+    ``prefix_sharing`` the engine prefills with L = bucket: cache rows that
+    would fall past the cache's end must be dropped, not shifted, and a
+    row's logits must equal those of the same tokens fed one at a time.
+    Without it L is always 1.
 
     ``init_cache(batch, pos_per_slot)`` builds a fresh cache pytree whose
     leaves carry the batch dim at axis 0 (axis 1 under the ``stacked_key``
@@ -312,8 +320,9 @@ class EngineBackend:
     stacked_key: str = "layers"   # cache subtree with a leading stack dim
     # True iff every non-``pos`` cache leaf is indexed by absolute sequence
     # position (see models.transformer.positional_cache) — the property
-    # prefix KV sharing needs; recurrent-state substrates set this False
-    # and the engine keeps cold prefills only
+    # prefix KV sharing and the one-pass (L = bucket) prefill need;
+    # recurrent-state substrates set this False and the engine keeps cold
+    # prefills that scan one-token decodes
     prefix_sharing: bool = True
     # placement for the engine's initial device state (rolling cache +
     # metrics table).  Mesh-aware backends should commit with the SAME
@@ -336,6 +345,7 @@ class EngineStats:
     slot_reuses: int = 0          # admissions into a previously-used slot
     generated_tokens: int = 0
     prefill_calls: int = 0        # prefill program invocations (k >= 1 each)
+    extend_prefills: int = 0      # of those, parallel extends (not scans)
     batched_admissions: int = 0   # admissions that shared a k > 1 prefill
     slot_steps: int = 0           # occupied slots summed over decode steps
     prefix_upload_bytes: int = 0      # prefix KV slabs sent to the device
@@ -489,14 +499,38 @@ class ContinuousEngine:
             def put(path, big, small):
                 keys = [getattr(e, "key", None) for e in path]
                 axis = 2 if stacked in keys else 1
-                return cache_span_update(big, small.astype(big.dtype),
-                                         jnp.int32(0), seq_axis=axis)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    big, small.astype(big.dtype), 0, axis=axis)
 
             kv = jax.tree_util.tree_map_with_path(put, kv, slabs)
             kv["pos"] = jnp.asarray(prefix_len, cachek["pos"].dtype)
             return kv
 
+        pad = cfg.pad_id
+        extend = self.backend.prefix_sharing
+
         def make_prefill(k: int, bucket: int):
+            def first_tokens(cachek, last, seeds):
+                sampled = sample_rows(last, seeds, jnp.zeros((k,), jnp.int32))
+                return cachek, sampled, metric_rows(last, sampled, eos)
+
+            def extend_suffix(params, cachek, toks, lengths, seeds):
+                # a row at position i depends only on tokens 0..i, so the
+                # suffix minus its last token prefills in ONE parallel
+                # extend (pad columns land past each row's prompt, masked
+                # by position until decode overwrites them) ...
+                start = cachek["pos"]
+                cols = jnp.arange(bucket)[None, :]
+                body = jnp.where(cols < (lengths - 1)[:, None], toks, pad)
+                _, cachek = decode(params, cachek, body)
+                # ... and the last token, decoded alone at its position,
+                # gives each row's first-token logits
+                last_tok = jnp.take_along_axis(toks, (lengths - 1)[:, None],
+                                               axis=1)
+                cachek = {**cachek, "pos": start + lengths - 1}
+                last, cachek = decode(params, cachek, last_tok)
+                return first_tokens(cachek, last, seeds)
+
             def scan_suffix(params, cachek, toks, lengths, seeds):
                 def body(carry, x):
                     cache, last = carry
@@ -509,18 +543,19 @@ class ContinuousEngine:
                 xs = (toks.T, jnp.arange(bucket))
                 (cachek, last), _ = jax.lax.scan(
                     body, (cachek, jnp.zeros((k, V), jnp.float32)), xs)
-                sampled = sample_rows(last, seeds,
-                                      jnp.zeros((k,), jnp.int32))
-                return cachek, sampled, metric_rows(last, sampled, eos)
+                return first_tokens(cachek, last, seeds)
 
+            prefill_suffix = extend_suffix if extend else scan_suffix
             if slab:
                 def prefill_impl(params, cachek, toks, lengths, seeds,
                                  prefix_leaves, prefix_len):
                     cachek = load_prefix(cachek, prefix_leaves, prefix_len)
-                    return scan_suffix(params, cachek, toks, lengths, seeds)
+                    return prefill_suffix(params, cachek, toks, lengths,
+                                          seeds)
             else:
                 def prefill_impl(params, cachek, toks, lengths, seeds):
-                    return scan_suffix(params, cachek, toks, lengths, seeds)
+                    return prefill_suffix(params, cachek, toks, lengths,
+                                          seeds)
 
             return jax.jit(prefill_impl, donate_argnums=(1,))
 
@@ -799,6 +834,8 @@ class ContinuousEngine:
                                                              i),
                             max_blocks=max_blocks)
             self.stats.prefill_calls += 1
+            if self.backend.prefix_sharing:
+                self.stats.extend_prefills += 1
             if k > 1:
                 self.stats.batched_admissions += k
 

@@ -143,3 +143,114 @@ def test_grad_flows_through_every_family():
         norms = [float(jnp.sum(jnp.square(x))) for x in jax.tree_util.tree_leaves(g)]
         assert all(np.isfinite(n) for n in norms), arch
         assert sum(norms) > 0, arch
+
+
+# ---------------------------------------------------------------------------
+# extend: decode_step over (B, L) tokens == L one-token decode steps
+# ---------------------------------------------------------------------------
+
+# At float32 an extend and L one-token steps agree to float32 rounding.  In
+# bfloat16 they round their matmuls over different shapes, so they may part
+# by bfloat16 rounding: no more than the one-token steps themselves part
+# from the same steps at float32.
+F32_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _max_gap(a, b):
+    return max(float(np.max(np.abs(np.asarray(x, np.float32)
+                                   - np.asarray(y, np.float32))))
+               for x, y in zip(jax.tree_util.tree_leaves(a),
+                               jax.tree_util.tree_leaves(b)))
+
+
+# one jitted decode_step for every shape the extend tests use
+_STEP = jax.jit(decode_step, static_argnums=(1,))
+
+
+def _history_cache(cfg, params, batch, max_seq, starts, per_row):
+    """A cache holding real history: max(starts) tokens decoded one at a
+    time, then each row's position set to its start (rows past a row's
+    start are stale, as a reused slot's are, and masked by position)."""
+    cache = init_cache(params, cfg, batch, max_seq, pos_per_slot=per_row)
+    n = max(starts)
+    hist = jax.random.randint(jax.random.PRNGKey(7), (batch, n), 0,
+                              cfg.vocab_size)
+    for i in range(n):
+        _, cache = _STEP(params, cfg, cache, hist[:, i:i + 1])
+    pos = jnp.asarray(starts, jnp.int32) if per_row else jnp.int32(starts[0])
+    return {**cache, "pos": pos}
+
+
+def _extend_vs_steps(cfg, starts, per_row, L, max_seq):
+    params, _ = init_params(cfg, KEY)
+    B = len(starts)
+    cache = _history_cache(cfg, params, B, max_seq, starts, per_row)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, L), 0,
+                              cfg.vocab_size)
+    ref = cache
+    for j in range(L):
+        ref_logits, ref = _STEP(params, cfg, ref, toks[:, j:j + 1])
+    got_logits, got = _STEP(params, cfg, cache, toks)
+    return ref_logits, ref, got_logits, got
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+@pytest.mark.parametrize("arch", [
+    "qwen3-0.6b",          # dense GQA attention
+    "gemma3-1b",           # sliding-window 'local' layers (window 8)
+    "deepseek-v2-236b",    # MLA latent cache
+    "jamba-v0.1-52b",      # mamba layers scan their columns in the extend
+])
+def test_extend_matches_one_token_decodes(arch, per_row, dtype):
+    """One decode_step over L tokens writes the same KV rows and gives the
+    same last-column logits as L one-token decode steps."""
+    def run(dt):
+        cfg = dataclasses.replace(get_config(arch, smoke=True),
+                                  dtype=jnp.dtype(dt).type)
+        return cfg, _extend_vs_steps(cfg, starts, per_row, 8, max_seq=24)
+
+    starts = [5, 9] if per_row else [7, 7]
+    cfg, (ref_logits, ref, got_logits, got) = run(dtype)
+    assert got_logits.shape == (2, 1, cfg.vocab_size)
+    np.testing.assert_array_equal(np.asarray(got["pos"]),
+                                  np.asarray(ref["pos"]))
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(got_logits),
+                                   np.asarray(ref_logits), **F32_TOL)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(ref)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       **F32_TOL)
+        return
+    _, (f32_logits, f32, _, _) = run("float32")
+    assert _max_gap(got_logits, ref_logits) <= \
+        _max_gap(ref_logits, f32_logits)
+    assert _max_gap(got, ref) <= _max_gap(ref, f32)
+
+
+def test_extend_past_cache_end_drops_pad_rows():
+    """A row whose span runs past the cache's end keeps its real rows in
+    place and drops the overflow: the cache equals the first S rows of the
+    same extend into a longer cache."""
+    cfg = _f32("qwen3-0.6b")
+    params, _ = init_params(cfg, KEY)
+    S, L, starts = 16, 8, [2, 12]              # row 1 spans 12..19
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, L), 0,
+                              cfg.vocab_size)
+    short = _history_cache(cfg, params, 2, S, starts, True)
+    long_ = _history_cache(cfg, params, 2, S + L, starts, True)
+    got_logits, got = _STEP(params, cfg, short, toks)
+    ref_logits, ref = _STEP(params, cfg, long_, toks)
+    np.testing.assert_array_equal(np.asarray(got["pos"]), [2 + L, 12 + L])
+    for key in ("k", "v"):                     # (layers, B, S, KV, hd)
+        np.testing.assert_allclose(
+            np.asarray(got["layers"]["slot_0"][key]),
+            np.asarray(ref["layers"]["slot_0"][key])[:, :, :S], **F32_TOL)
+    # rows before the overflowing row's start were not shifted
+    np.testing.assert_array_equal(
+        np.asarray(got["layers"]["slot_0"]["k"][:, 1, :12]),
+        np.asarray(short["layers"]["slot_0"]["k"][:, 1, :12]))
+    # the row that fits attends to the same keys either way
+    np.testing.assert_allclose(np.asarray(got_logits[0]),
+                               np.asarray(ref_logits[0]), **F32_TOL)

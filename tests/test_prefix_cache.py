@@ -345,6 +345,23 @@ class TestCacheSpanUpdate:
         want[:, :, 3:7] = new
         np.testing.assert_array_equal(got, want)
 
+    @pytest.mark.parametrize("per_row", [False, True],
+                             ids=["scalar", "per_row"])
+    def test_span_past_the_end_is_dropped_not_shifted(self, per_row):
+        rng = np.random.default_rng(2)
+        cache = rng.normal(size=(2, 10, 3)).astype(np.float32)
+        new = rng.normal(size=(2, 4, 3)).astype(np.float32)
+        pos = np.array([1, 8], np.int32) if per_row else np.int32(8)
+        got = np.asarray(cache_span_update(jnp.asarray(cache),
+                                           jnp.asarray(new),
+                                           jnp.asarray(pos)))
+        want = cache.copy()
+        for b in range(2):
+            start = int(pos[b]) if per_row else int(pos)
+            n = min(4, 10 - start)
+            want[b, start:start + n] = new[b, :n]
+        np.testing.assert_array_equal(got, want)
+
     def test_single_row_delegates_to_row_update(self):
         cache = jnp.zeros((2, 6), jnp.int32)
         out = cache_row_update(cache, jnp.asarray([[5], [7]], jnp.int32),

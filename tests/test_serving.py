@@ -15,6 +15,8 @@ Most tests drive a tiny deterministic toy backend (no model) so the slot
 machinery is exercised in milliseconds; one class runs the real smoke
 model end-to-end.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -22,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from _hyp import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
-from repro.models.attention import cache_row_update, decode_positions
+from repro.models.attention import cache_span_update, decode_positions
 from repro.runtime import RequestBatcher
 from repro.serving import ContinuousEngine, EngineBackend, Request, ServeConfig
 
@@ -36,17 +38,19 @@ def toy_decode(params, cache, cur):
     Row-independent by construction (everything is per-row), uses the cache
     position exactly like real attention does: reads only history at
     positions <= its own pos, so stale KV from a prior occupant is
-    unreadable iff the engine's slot handoff is sound.
+    unreadable iff the engine's slot handoff is sound.  Honours the
+    backend's ``(B, L)`` contract: the L tokens land at pos..pos+L-1 and
+    the logits are the last column's, as L one-token calls would give.
     """
-    batch = cur.shape[0]
+    batch, length = cur.shape
     pos = cache["pos"]
-    posb = decode_positions(pos, batch)[:, 0]
-    hist = cache_row_update(cache["hist"], cur, pos)
+    posb = decode_positions(pos, batch, length)[:, -1]
+    hist = cache_span_update(cache["hist"], cur, pos)
     valid = jnp.arange(hist.shape[1])[None, :] <= posb[:, None]
     s = jnp.sum(hist * valid, axis=1)
     tgt = (s * params["a"] + posb * params["b"]) % VOCAB
     logits = 5.0 * jax.nn.one_hot(tgt, VOCAB) + 0.01 * jnp.arange(VOCAB)
-    return logits.astype(jnp.float32), {"pos": pos + 1, "hist": hist}
+    return logits.astype(jnp.float32), {"pos": pos + length, "hist": hist}
 
 
 def toy_init_cache(batch, pos_per_slot):
@@ -256,6 +260,34 @@ class TestContinuousEngine:
                 (ref.tokens, ref.logprob_sum, ref.stopped)
 
 
+    def test_extend_prefill_matches_scan_prefill(self):
+        """A positional backend prefills each chunk in one parallel extend;
+        with ``prefix_sharing`` off the same prompts scan one-token decodes.
+        Both give the same tokens and (integer toy) the same logprobs."""
+        trace = [([1, 2, 3, 4, 5, 6], 4), ([7, 8], 6), ([9], 3),
+                 ([10, 11, 12], 3), ([1, 3, 5, 7, 9, 11, 2, 4], 5),
+                 ([2, 4], 2), ([3, 1, 4, 1, 5], 4)]
+        runs = []
+        for sharing in (True, False):
+            backend = toy_backend()
+            backend.prefix_sharing = sharing
+            eng = ContinuousEngine(backend, ServeConfig(
+                num_slots=3, prefill_buckets=(2, 4, 8), max_new_tokens=6,
+                eos_id=-7, prefill_batch=2, prefix_cache=False))
+            uids = [eng.submit(p, max_new_tokens=m) for p, m in trace]
+            drain(eng)
+            assert sum(eng.compile_counts().values()) <= eng.compile_bound()
+            runs.append((eng, [eng.result(u) for u in uids]))
+        (ext, ext_res), (scan, scan_res) = runs
+        assert ext.stats.extend_prefills == ext.stats.prefill_calls > 0
+        assert ext.stats.batched_admissions > 0
+        assert scan.stats.extend_prefills == 0
+        assert scan.stats.prefill_calls > 0
+        for a, b in zip(ext_res, scan_res):
+            assert (a.tokens, a.logprob_sum, a.stopped) == \
+                (b.tokens, b.logprob_sum, b.stopped)
+
+
 @settings(max_examples=15, deadline=None)
 @given(st.lists(
     st.tuples(st.lists(st.integers(1, VOCAB - 1), min_size=1, max_size=8),
@@ -352,6 +384,28 @@ class TestRealModelServing:
             assert got.tokens == ref.tokens
             assert got.logprob_sum == ref.logprob_sum   # bitwise
         assert warm.result(u2).bucket == 4              # suffix bucket
+
+    def test_extend_prefill_matches_scan_prefill(self, engine_factory):
+        """The real model's one-pass extend prefill and its scan of
+        one-token decodes (the path non-positional backends keep) serve the
+        same tokens; logprobs agree to float32 rounding, since the two
+        prefills round matmuls over different shapes."""
+        prompts = [[5, 9, 2, 7, 11, 3, 8], [6, 6], [8, 1, 4, 4, 2]]
+        ext = engine_factory(prefix_cache=False, prefill_batch=2)
+        scan = ContinuousEngine(
+            dataclasses.replace(ext.backend, prefix_sharing=False),
+            ext.config)
+        got = {}
+        for eng in (ext, scan):
+            uids = [eng.submit(p) for p in prompts]
+            drain(eng)
+            got[id(eng)] = [eng.result(u) for u in uids]
+        assert ext.stats.extend_prefills == ext.stats.prefill_calls > 0
+        assert scan.stats.extend_prefills == 0
+        for a, b in zip(got[id(ext)], got[id(scan)]):
+            assert a.tokens == b.tokens
+            assert a.logprob_sum == pytest.approx(b.logprob_sum,
+                                                  rel=LOGPROB_RTOL)
 
     def test_run_batched_decode_shim(self, engine_factory, solo_engine):
         from repro.runtime import DecodeBatch
