@@ -23,7 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from chipbench.device import CompileCounter, memory_peak_bytes
 from chipbench.record import Run, Span, WindowTracer
-from reference import qwen3, wordcount
+from reference import wordcount
+from reference.seeds import seed_key
 
 AXIS = "data"
 # finished jobs compared besides the last, partial one
@@ -55,7 +56,7 @@ def make_splits(seed: int, config: dict, mesh) -> List[jax.Array]:
             out.append(perm[rank].astype(jnp.int32))
         return tuple(out)
 
-    return list(draw(qwen3.seed_key(seed), cdf))
+    return list(draw(seed_key(seed), cdf))
 
 
 def fold_programs(config: dict, mesh, *, fault: Optional[str] = None,

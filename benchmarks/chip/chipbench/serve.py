@@ -1,14 +1,21 @@
 """Serve cells: the continuous-batching engine under an open-loop mix.
 
-Set-up makes the weights on the chip in one jitted call from the seed
-(``reference/qwen3.py``'s generator, cast to bfloat16 and laid out as the
-program's parameters), wires them into the program's engine the way
+Nothing here knows a model.  The configuration's ``reference`` key names
+the plain reference (``reference/<model>.py``), and its program adapter
+(``programs/<model>.py``) maps the file onto the program: see
+``chipbench/spec.py`` for what each provides.
+
+Set-up makes the weights on the chip in one jitted call from the seed (the
+reference's generator, in the type they are served in), lays them out as
+the program's parameters through the adapter, holds that tree to the
+program's own shapes, wires it into the program's engine the way
 ``repro.launch.serve.build_engine`` does, and warms every program the
 mix's shapes use.  The window then submits each request when it is due,
 steps the engine whenever it has work, and times every request from its
-due time on the engine's own clock.  Requests due in the window are served
-to the end after it closes.  The check compares a sample of the served
-requests with the float32 reference.
+due time on the engine's own clock; each decode step carries the model
+FLOPs the reference counts for its live requests.  Requests due in the
+window are served to the end after it closes.  The check compares a sample
+of the served requests with the reference's float32 logits.
 """
 from __future__ import annotations
 
@@ -22,11 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
 from chipbench import traffic as traffic_mod
 from chipbench.device import CompileCounter, memory_peak_bytes
 from chipbench.record import Run, Span, WindowTracer
-from metrics.common import counts
-from reference import qwen3
 
 # requests still unfinished this long after the window closed count failed
 DRAIN_LIMIT_S = 120.0
@@ -35,52 +41,13 @@ DRAIN_LIMIT_S = 120.0
 NO_EOS = -1
 
 
-def program_config(config: dict):
-    """The program's model configuration, held to the configuration file."""
-    from repro.configs import get_config
-
-    cfg = get_config(config["program_arch"],
-                     smoke=bool(config.get("program_smoke", False)))
-    same = {"d_model": "hidden_size", "num_layers": "num_hidden_layers",
-            "num_heads": "num_attention_heads",
-            "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
-            "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-            "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps",
-            "tie_embeddings": "tie_word_embeddings",
-            "qkv_bias": "attention_bias"}
-    diff = {k: (getattr(cfg, p), config[k]) for p, k in same.items()
-            if getattr(cfg, p) != config[k]}
-    if not (cfg.qk_norm and cfg.gated_ffn and cfg.act_fn == "silu"
-            and cfg.family == "dense" and cfg.layer_pattern == ("attn",)):
-        diff["block"] = "not a Qwen3 dense block"
-    if diff:
-        raise ValueError(f"{config['program_arch']}: the program's "
-                         f"configuration differs from the file: {diff}")
-    return cfg
-
-
-@jax.jit
-def _program_layout(w: dict) -> dict:
-    lay = w["layers"]
-    L, D, _ = lay["wq"].shape
-    hd = lay["q_norm"].shape[1]
-    slot = {"norm1": lay["norm1"], "norm2": lay["norm2"],
-            "mix": {"wq": lay["wq"].reshape(L, D, -1, hd),
-                    "wk": lay["wk"].reshape(L, D, -1, hd),
-                    "wv": lay["wv"].reshape(L, D, -1, hd),
-                    "wo": lay["wo"].reshape(L, -1, hd, D),
-                    "q_norm": lay["q_norm"], "k_norm": lay["k_norm"]},
-            "ffn": {k: lay[k] for k in ("w_gate", "w_up", "w_down")}}
-    return {"embed": w["embed"], "final_norm": w["final_norm"],
-            "layers": {"slot_0": slot}}
-
-
-def program_params(weights: dict, cfg) -> dict:
-    """The generated weights in the program's parameter tree; its structure,
-    shapes and dtypes must be exactly the program's."""
+def program_params(adapter, weights: dict, cfg) -> dict:
+    """The generated weights in the program's parameter tree, as ``adapter``
+    lays them out; its structure, shapes and dtypes must be exactly the
+    program's."""
     from repro.models import param_shapes
 
-    params = _program_layout(weights)
+    params = adapter.program_params(weights, cfg)
     want = param_shapes(cfg)
     got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
     need = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), want)
@@ -177,13 +144,15 @@ class ServeCell:
     def __init__(self, config: dict, mix: dict, seed: int, devs, *,
                  fault: Optional[str] = None):
         self.config, self.mix, self.seed, self.devs = config, mix, seed, devs
-        self.sizes = qwen3.Sizes.from_config(config)
+        self.model = spec.reference(config)
+        adapter = spec.program_adapter(config)
+        self.sizes = self.model.Sizes.from_config(config)
         server = dict(mix["server"],
                       prefix_capacity=config["serving"]["prefix_capacity"])
         self.server = server
-        cfg = program_config(config)
-        weights = qwen3.make_weights(seed, self.sizes, devs[0])
-        params = program_params(weights, cfg)
+        cfg = adapter.program_config(config)
+        weights = self.model.make_weights(seed, self.sizes, devs[0])
+        params = program_params(adapter, weights, cfg)
         del weights
         self.engine = make_engine(cfg, params, server, devs, fault)
 
@@ -279,7 +248,7 @@ class ServeCell:
                 admit = eng.pending > 0 and eng.num_active < slots
                 attrs = {}
                 if not admit:
-                    attrs["flops"] = counts.decode_flops(
+                    attrs["flops"] = self.model.decode_flops(
                         self.config, [len(r.req.prompt) + len(r.tokens)
                                       for r in live.values()])
                 name = "admit_step" if admit else "decode_step"
@@ -364,8 +333,8 @@ class ServeCell:
         reference's best, and the widest relative gap of a request's
         logprob sum.  With ``control`` also the same numbers for the fp8
         reference put in the program's place."""
-        s = self.sizes
-        weights = qwen3.make_weights(self.seed, s, self.devs[0])
+        s, model = self.sizes, self.model
+        weights = model.make_weights(self.seed, s, self.devs[0])
         length = self.server["prefill_buckets"][-1] \
             + self.server["max_new_tokens"]
         width = self.server["max_new_tokens"]
@@ -375,7 +344,7 @@ class ServeCell:
         for rec in sample:
             toks = np.asarray(rec.tokens)
             rows = np.arange(len(toks))
-            ref = qwen3.served_logits(weights, rec.req.prompt, rec.tokens, s,
+            ref = model.served_logits(weights, rec.req.prompt, rec.tokens, s,
                                       length, width)
             best = ref.max(axis=-1)
             ref_sum = float(_log_softmax(ref)[rows, toks].sum())
@@ -385,7 +354,7 @@ class ServeCell:
                 out["logprob_rel"],
                 abs(rec.logprob_sum - ref_sum) / abs(ref_sum))
             if control:
-                low = qwen3.served_logits(weights, rec.req.prompt, rec.tokens,
+                low = model.served_logits(weights, rec.req.prompt, rec.tokens,
                                           s, length, width, fp8=True)
                 first = low.argmax(axis=-1)
                 low_sum = float(_log_softmax(low)[rows, toks].sum())

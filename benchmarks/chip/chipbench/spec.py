@@ -7,6 +7,36 @@ per-layer metric is a file found by its name:
   traffic/<traffic>.json     the mix: a ``kind`` and its parameters
   limits/<workload>.json     the limits of the cell's correctness check
   metrics/<metric>.py        a reader with ``read(run) -> float | None``
+
+A configuration's ``reference`` key names, relative to this directory, its
+plain reference, ``reference/<model>.py``; a serve configuration's program
+adapter is ``programs/<model>.py``, beside it.  A configuration cut to fit
+its chips lists the cut keys under ``reduced``, as ``BENCHMARK.json`` does.
+
+A serve reference imports nothing of the program and provides
+
+  Sizes.from_config(config)  the model's shape, with a ``vocab_size``
+  make_weights(seed, sizes, device=None)
+                             every weight, in the type it is served in,
+                             drawn in one jitted call from the seed
+  logits_at(weights, tokens, read, sizes, fp8=False)
+                             jitted float32 logits at positions ``read``
+  served_logits(weights, prompt, served, sizes, length, width, fp8=False)
+                             the logits before each served token, as numpy
+  decode_flops(config, keys_per_token)
+                             one decode step's model FLOPs
+
+and its adapter provides
+
+  program_config(config)     the program's ``ModelConfig``; raises where the
+                             program differs from the file
+  program_params(weights, cfg)
+                             the weights as the program's parameter tree
+
+So a serve configuration is added as new files only: ``configs/<name>.json``,
+``reference/<model>.py`` and ``programs/<model>.py`` (where the model is
+new), ``limits/<workload>.json``, any new ``traffic/<traffic>.json``, and
+its entries in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
@@ -15,6 +45,7 @@ import importlib.util
 import json
 import pathlib
 import re
+import sys
 from typing import Dict, List, Optional
 
 CHIP_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -116,6 +147,12 @@ def load_cell(workload: str, bench: Optional[dict] = None,
     w = by_name[workload]
     entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = load_json(chip_dir.parents[1] / entry["file"])
+    if sorted(config.get("reduced", [])) != sorted(entry["reduced"]):
+        raise ValueError(f"{entry['file']}: reduced "
+                         f"{config.get('reduced', [])} differs from "
+                         f"BENCHMARK.json's {entry['reduced']}")
+    if "reference" in config:
+        reference(config, chip_dir)
     traffic = load_json(chip_dir / "traffic" / f"{w['traffic']}.json")
     limits_path = chip_dir / "limits" / f"{workload}.json"
     limits = load_json(limits_path) if limits_path.exists() else {}
@@ -127,16 +164,49 @@ def load_cell(workload: str, bench: Optional[dict] = None,
                 end_to_end=e2e, per_layer=per_layer)
 
 
+def _module(path: pathlib.Path, what: str, chip_dir: pathlib.Path):
+    """The module at ``path``, loaded once per process."""
+    path = path.resolve()
+    if chip_dir.resolve() not in path.parents:
+        raise ValueError(f"{what} {path} is outside {chip_dir}")
+    if not path.is_file():
+        raise FileNotFoundError(f"no {what} at {path}")
+    rel = path.relative_to(chip_dir.resolve()).with_suffix("")
+    mod_name = "chip_" + re.sub(r"[^A-Za-z0-9_]", "_", str(rel))
+    known = sys.modules.get(mod_name)
+    if known is not None and known.__file__ != str(path):
+        raise ValueError(f"{what} {path} and {known.__file__} share the "
+                         f"module name {mod_name}")
+    if known is None:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod        # dataclasses look their module up
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[mod_name]
+            raise
+    return sys.modules[mod_name]
+
+
 def metric_reader(name: str, chip_dir: pathlib.Path = CHIP_DIR):
     """The ``read`` function of ``metrics/<name>.py``."""
     path = chip_dir / "metrics" / f"{check_name(name, 'metric')}.py"
-    if not path.exists():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    mod_name = "chip_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"reader for metric {name!r}", chip_dir).read
+
+
+def reference(config: dict, chip_dir: pathlib.Path = CHIP_DIR):
+    """The module that the configuration's ``reference`` key names."""
+    return _module(chip_dir / config["reference"],
+                   f"reference for {config['name']!r}", chip_dir)
+
+
+def program_adapter(config: dict, chip_dir: pathlib.Path = CHIP_DIR):
+    """The program adapter of the configuration's reference:
+    ``programs/<model>.py`` beside ``reference/<model>.py``."""
+    ref = chip_dir / config["reference"]
+    return _module(ref.parents[1] / "programs" / ref.name,
+                   f"program adapter for {config['name']!r}", chip_dir)
 
 
 def read_metrics(metrics: List[dict], run) -> Dict[str, dict]:

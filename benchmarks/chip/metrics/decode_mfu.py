@@ -1,7 +1,8 @@
-"""Model: the decode steps' model FLOPs (2 x multiplied weights per token,
-plus attention over each request's live length) over their host time times
-the chip's bf16 peak.  The whole step's share of the peak, not a kernel's.
-Moves ``tpot_ms``."""
+"""Model: the decode steps' model FLOPs over their host time times the
+chip's bf16 peak.  The FLOPs are 2 x the weights a token is multiplied by,
+as the configuration's reference counts them (for a mixture of experts, the
+experts it is routed to), plus attention over each request's live length.
+The whole step's share of the peak, not a kernel's.  Moves ``tpot_ms``."""
 
 
 def read(run):

@@ -22,6 +22,9 @@ and there is no sampling, cache or batching.
 ``fp8=True`` is the precision control: every matrix-product operand is
 rounded to float8 e4m3 with one scale per tensor before an exact float32
 product, as an fp8 serving path would compute it.
+
+The serve harness finds this module by the configuration file's
+``reference`` key, and its program adapter as ``programs/qwen3.py``.
 """
 from __future__ import annotations
 
@@ -32,6 +35,9 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from metrics.common import counts
+from reference.seeds import seed_key
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # per-layer weights, in the order they are drawn from the seed
@@ -71,14 +77,6 @@ def layer_shapes(s: Sizes) -> dict:
             "w_up": (D, F), "w_down": (F, D)}
 
 
-def seed_key(seed: int) -> jax.Array:
-    """A PRNG key from any non-negative seed, also one wider than 32 bits."""
-    if seed < 0:
-        raise ValueError(f"seed must be >= 0, got {seed}")
-    return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
-                              (seed >> 32) & 0xFFFFFFFF)
-
-
 @functools.partial(jax.jit, static_argnums=(1,))
 def _make(key: jax.Array, s: Sizes) -> dict:
     L = s.num_hidden_layers
@@ -106,6 +104,34 @@ def make_weights(seed: int, s: Sizes, device=None) -> dict:
     if device is not None:
         key = jax.device_put(key, device)
     return _make(key, s)
+
+
+# ---------------------------------------------------------------------------
+# work per token, from the shapes alone
+# ---------------------------------------------------------------------------
+
+def matmul_params(c: dict) -> int:
+    """Weights a token is multiplied by: every layer's projections and FFN,
+    and the tied embedding once, as the output head (the embedding lookup
+    is a gather)."""
+    D, H, KV, hd, F = (c["hidden_size"], c["num_attention_heads"],
+                       c["num_key_value_heads"], c["head_dim"],
+                       c["intermediate_size"])
+    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
+    return c["num_hidden_layers"] * per_layer + c["vocab_size"] * D
+
+
+def attention_flops_per_key(c: dict) -> int:
+    """QK^T and AV of one query token for each cached position it reads."""
+    return 4 * c["num_hidden_layers"] * c["num_attention_heads"] \
+        * c["head_dim"]
+
+
+def decode_flops(c: dict, keys_per_token) -> int:
+    """One decode step: one token per live request, each attending over its
+    own number of keys."""
+    return counts.decode_flops(matmul_params(c), attention_flops_per_key(c),
+                               keys_per_token)
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,21 @@
 #!/usr/bin/env python3
-"""Compile the cells' main programs for a described TPU v5e, no chip needed.
+"""Compile a cell's main programs for a described TPU v5e, no chip needed.
 
-  JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py
+  JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py \\
+      --workload qwen3-0.6b.chat-prefix
 
-Prints each program's memory analysis as one JSON line: the serve decode
-step at the chat cells' 32 slots x 1280-row cache, one batched prefill
-token at (4, 1280), the float32 reference's logits program, and the
-four-chip word count fold at 2**24 ids per chip (``chipbench/fold.py``,
-which no cell runs yet).  Shapes only: nothing is
-allocated or run.  A compile that passes here is not a chip run.
+The cell, its configuration, reference and program adapter are read as
+``run.py`` reads them.  Prints each program's memory analysis as one JSON
+line.  A serve cell: the decode step at the mix's slots x (largest bucket
++ max_new_tokens) cache, one batched prefill token at (prefill_batch,
+that length), and the float32 reference's logits program.  A fold cell:
+the word count fold at the configuration's ids per chip on the cell's
+chips.  Shapes only: nothing is allocated or run.  A compile that passes
+here is not a chip run.
 """
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import pathlib
@@ -24,7 +28,8 @@ sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
+from jax.sharding import (NamedSharding, PartitionSpec,  # noqa: E402
+                          SingleDeviceSharding)
 
 
 def sds(tree, sharding):
@@ -41,16 +46,19 @@ def memory(compiled) -> dict:
     return {k: int(getattr(m, k)) for k in keys if hasattr(m, k)}
 
 
-def serve_programs(one, slots=32, seq=1280, k=4):
-    from chipbench import serve
-    from reference import qwen3
+def serve_programs(cell, devices) -> dict:
+    from chipbench import serve, spec
     from repro.models import RunCtx, decode_step, init_cache
 
-    config = json.loads((HERE / "configs" / "qwen3-0.6b.json").read_text())
-    cfg = serve.program_config(config)
-    sizes = qwen3.Sizes.from_config(config)
-    weights = jax.eval_shape(lambda: qwen3._make(qwen3.seed_key(0), sizes))
-    params = jax.eval_shape(serve._program_layout, weights)
+    config, server = cell.config, cell.traffic["server"]
+    ref, adapter = spec.reference(config), spec.program_adapter(config)
+    one = SingleDeviceSharding(devices[0])
+    cfg = adapter.program_config(config)
+    sizes = ref.Sizes.from_config(config)
+    weights = jax.eval_shape(lambda: ref.make_weights(0, sizes))
+    params = jax.eval_shape(
+        lambda w: serve.program_params(adapter, w, cfg), weights)
+    seq = server["prefill_buckets"][-1] + server["max_new_tokens"]
     ctx = RunCtx()
 
     def step(p, cache, cur):
@@ -58,7 +66,8 @@ def serve_programs(one, slots=32, seq=1280, k=4):
         return jnp.argmax(logits[:, -1], -1), cache
 
     out = {}
-    for name, batch in (("serve_decode_step", slots), ("prefill_token", k)):
+    for name, batch in (("serve_decode_step", server["num_slots"]),
+                        ("prefill_token", server["prefill_batch"])):
         cache = jax.eval_shape(lambda p, b=batch: init_cache(
             p, cfg, b, seq, pos_per_slot=True), params)
         args = (sds(params, one), sds(cache, one),
@@ -67,21 +76,20 @@ def serve_programs(one, slots=32, seq=1280, k=4):
         c = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
         out[name] = dict(memory(c), compile_s=round(time.time() - t, 1))
     toks = jax.ShapeDtypeStruct((seq,), jnp.int32, sharding=one)
-    read = jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
+    read = jax.ShapeDtypeStruct((server["max_new_tokens"],), jnp.int32,
+                                sharding=one)
     t = time.time()
-    c = jax.jit(qwen3.logits_at, static_argnames=("s", "fp8")).lower(
-        sds(weights, one), toks, read, s=sizes).compile()
+    c = ref.logits_at.lower(sds(weights, one), toks, read, sizes).compile()
     out["reference_logits"] = dict(memory(c),
                                    compile_s=round(time.time() - t, 1))
     return out
 
 
-def fold_program(devices):
+def fold_program(cell, devices) -> dict:
     from repro.core import word_count_job
     from repro.launch.mesh import make_mesh
 
-    config = json.loads((HERE / "configs" /
-                         "wordcount-zipf-152k.json").read_text())
+    config = cell.config
     mesh = make_mesh((len(devices),), ("data",), devices)
     job = word_count_job(config["num_keys"])
     ids = jax.ShapeDtypeStruct((config["split_ids"] * len(devices),),
@@ -96,19 +104,24 @@ def fold_program(devices):
     text = c.as_text()
     colls = sorted({w for w in ("reduce-scatter", "all-gather", "all-reduce")
                     if w in text})
-    return {"fold_4chip": dict(memory(c), collectives=colls,
-                               compile_s=round(time.time() - t, 1))}
+    return {f"fold_{len(devices)}chip": dict(
+        memory(c), collectives=colls, compile_s=round(time.time() - t, 1))}
 
 
-def main() -> int:
+def main(argv=None) -> int:
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
+    from chipbench import spec
+
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    args = ap.parse_args(argv)
+    cell = spec.load_cell(args.workload)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    one = SingleDeviceSharding(topo.devices[0])
-    programs = {**serve_programs(one), **fold_program(list(topo.devices))}
-    for k, v in programs.items():
+    devices = list(topo.devices)[:cell.chips]
+    kinds = {"serve": serve_programs, "fold": fold_program}
+    for k, v in kinds[cell.traffic["kind"]](cell, devices).items():
         print(json.dumps({"program": k, **v}), flush=True)
     return 0
 

@@ -9,6 +9,7 @@ import pytest
 
 from conftest import CHIP
 from chipbench import serve
+from programs import qwen3 as program
 from reference import qwen3
 
 FIXTURES = CHIP / "tests" / "fixtures"
@@ -17,7 +18,8 @@ FIXTURES = CHIP / "tests" / "fixtures"
 @pytest.fixture(scope="module")
 def smoke():
     config = json.loads((FIXTURES / "qwen3-smoke.json").read_text())
-    cfg = dataclasses.replace(serve.program_config(config), dtype=jnp.float32)
+    cfg = dataclasses.replace(program.program_config(config),
+                              dtype=jnp.float32)
     sizes = qwen3.Sizes.from_config(config)
     w = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
                                qwen3.make_weights(2**33 + 7, sizes))
@@ -38,7 +40,7 @@ def test_forward_matches_the_program(smoke):
     from repro.models import forward, unembed
 
     cfg, sizes, w = smoke
-    params = serve.program_params(w, cfg)
+    params = serve.program_params(program, w, cfg)
     toks = np.random.default_rng(0).integers(1, sizes.vocab_size, 24)
     h, _ = forward(params, cfg, jnp.asarray(toks)[None])
     prog = np.asarray(unembed(params, cfg, h)[0])
@@ -51,7 +53,7 @@ def test_cached_decode_matches_the_reference(smoke):
     from repro.models import decode_step, init_cache
 
     cfg, sizes, w = smoke
-    params = serve.program_params(w, cfg)
+    params = serve.program_params(program, w, cfg)
     prompt = [5, 9, 200, 17, 3, 77]
     served = [11, 400, 8, 64]
     cache = init_cache(params, cfg, 1, 16, pos_per_slot=True)
