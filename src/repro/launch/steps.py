@@ -67,6 +67,7 @@ _CACHE_AXES = {
     "sl_h": ("batch", "heads", None),
     "sl_c": ("batch", "heads", None),
     "pos": (),
+    "route": ("batch", None),
 }
 
 

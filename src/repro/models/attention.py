@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from . import common
-from .common import ModelConfig, ParamBuilder, dense, rms_norm, rotary_embed
+from .common import (ModelConfig, ParamBuilder, attn_softmax_scale, dense,
+                     rms_norm, rotary_embed)
 from ..dist import sharding as shd
 from ..core import monoids
 from ..core.aggregation import monoid_allreduce
@@ -508,7 +509,7 @@ def _mla_q(p: Dict, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray):
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = rotary_embed(q_rope, positions, cfg.rope_theta)
+    q_rope = rotary_embed(q_rope, positions, cfg.rope_theta, cfg)
     return q_nope, q_rope
 
 
@@ -518,10 +519,12 @@ def _mla_latent(p: Dict, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarra
     ckv = dense(x, p["wkv_a"])                                  # (B,S,kvl+dr)
     c, k_rope = ckv[..., :kvl], ckv[..., kvl:]
     c = rms_norm(c, p["kv_a_norm"], cfg.norm_eps)
-    k_rope = rotary_embed(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = rotary_embed(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                          cfg)[:, :, 0]
     return c, k_rope
 
 
+@jax.named_scope("mla")
 def mla_attention(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                   positions: jnp.ndarray) -> jnp.ndarray:
     """Training/prefill MLA: up-project the latent, standard MHA."""
@@ -530,7 +533,7 @@ def mla_attention(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     c, k_rope = _mla_latent(p, cfg, x, positions)
     k_nope = jnp.einsum("bsl,lhk->bshk", c, p["wk_b"].astype(x.dtype))
     v = jnp.einsum("bsl,lhk->bshk", c, p["wv_b"].astype(x.dtype))
-    scale = 1.0 / math.sqrt(dn + cfg.qk_rope_dim)
+    scale = attn_softmax_scale(cfg, dn + cfg.qk_rope_dim)
     s_nope = jnp.einsum("bqhk,bshk->bhqs", q_nope, k_nope,
                         preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bqhk,bsk->bhqs", q_rope, k_rope,
@@ -547,6 +550,7 @@ def mla_attention(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     return shd.act(out, ("batch", "seq", "embed"))
 
 
+@jax.named_scope("mla")
 def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                cache: Tuple[jnp.ndarray, jnp.ndarray],
                pos: jnp.ndarray) -> Tuple[jnp.ndarray, Tuple]:
@@ -578,7 +582,7 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                        preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bqhk,bsk->bhqs", q_rope, r_cache,
                         preferred_element_type=jnp.float32)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = attn_softmax_scale(cfg, cfg.qk_nope_dim + cfg.qk_rope_dim)
     scores = (s_lat + s_rope) * scale
     k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     keep = _causal_mask(positions, k_pos)[:, None]

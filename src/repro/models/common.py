@@ -58,6 +58,17 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     num_padded_experts: int = 0      # trailing experts masked out of routing
                                      # (qwen2-moe: 60 real + 4 pads for EP=16)
+    # group-limited greedy routing (DeepSeek-V2): the experts form n_group
+    # equal groups, a token keeps the topk_group groups with the largest
+    # best score and picks its top-k among their experts; 1 = plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0   # gate weights x this when not normed
+    # one device's share of an expert-parallel layer: it holds experts
+    # first_held_expert .. + num_held_experts - 1 of the router's
+    # num_experts (0 = all); pairs routed elsewhere add nothing here
+    num_held_experts: int = 0
+    first_held_expert: int = 0
     # -- MLA (DeepSeek-V2)
     use_mla: bool = False
     q_lora_rank: int = 0
@@ -65,6 +76,13 @@ class ModelConfig:
     qk_rope_dim: int = 64
     qk_nope_dim: int = 128
     v_head_dim: int = 128
+    # YaRN rope scaling (DeepseekV2YarnRotaryEmbedding); factor 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # -- SSM (Mamba)
     d_state: int = 16
     d_conv: int = 4
@@ -94,6 +112,16 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         assert len(self.layer_pattern) == len(self.ffn_pattern), (
             "layer_pattern and ffn_pattern must be slot-aligned")
+        if self.num_experts:
+            assert self.num_experts % self.n_group == 0, (
+                "experts must split into equal routing groups")
+            assert self.first_held_expert + self.experts_held \
+                <= self.num_experts, "held experts beyond the router"
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.num_held_experts or self.num_experts
 
     # -- layer program ----------------------------------------------------------
     @property
@@ -134,7 +162,7 @@ class ModelConfig:
         # each routed expert is 3 matrices of d_model x d_ff_expert
         per_expert = 3 * self.d_model * self.d_ff_expert
         n_moe_layers = sum(1 for i in range(self.num_layers) if self.ffn_type(i) == "moe")
-        inactive = (self.num_experts - self.moe_top_k) * per_expert * n_moe_layers
+        inactive = (self.experts_held - self.moe_top_k) * per_expert * n_moe_layers
         return total - inactive
 
 
@@ -227,21 +255,77 @@ def rms_norm(x: jnp.ndarray, gamma: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarr
     return x * inv * gamma.astype(x.dtype)
 
 
-def rotary_embed(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(cfg: ModelConfig, dim: int) -> Tuple[int, int]:
+    """The rotary pairs between which YaRN blends interpolated and original
+    frequencies: those that turn beta_fast and beta_slow times over the
+    original context, floored and ceiled and clamped to [0, dim - 1]."""
+    def pair(rotations):
+        return dim * math.log(cfg.yarn_original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+    return (max(math.floor(pair(cfg.yarn_beta_fast)), 0),
+            min(math.ceil(pair(cfg.yarn_beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> jnp.ndarray:
+    """YaRN's inverse frequencies of the dim // 2 rotated pairs: pair i
+    blends the original frequency base^(-2i/dim) with it divided by
+    ``yarn_factor``: the original below the correction range, the divided
+    one above it, a linear ramp between."""
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = jnp.exp(-math.log(cfg.rope_theta) * 2 * i / dim)
+    low, high = yarn_correction_range(cfg, dim)
+    keep = 1.0 - jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / cfg.yarn_factor * (1.0 - keep) + extra * keep
+
+
+def yarn_rope_mscale(cfg: ModelConfig) -> float:
+    """YaRN's factor on cos and sin: mscale over mscale_all_dim (1 for
+    DeepSeek-V2, where the two are equal)."""
+    return yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+        / yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+
+
+def attn_softmax_scale(cfg: ModelConfig, head_dim: int) -> float:
+    """head_dim^-0.5, times YaRN's mscale(factor, mscale_all_dim)^2 when the
+    configuration sets ``yarn_mscale_all_dim``."""
+    scale = 1.0 / math.sqrt(head_dim)
+    if cfg.yarn_factor > 0 and cfg.yarn_mscale_all_dim:
+        scale *= yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def rotary_embed(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+                 cfg: Optional[ModelConfig] = None) -> jnp.ndarray:
     """Apply rotary position embedding.
 
     x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
-    Rotates pairs (x[2i], x[2i+1]) — the HF 'half-split' convention.
+    Rotates pairs (x[i], x[i + head_dim/2]) — the HF 'half-split'
+    convention.  ``cfg`` with a ``yarn_factor`` gives YaRN's frequencies
+    and cos/sin factor (:func:`yarn_inv_freq`, :func:`yarn_rope_mscale`).
     """
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half)
+    if cfg is not None and cfg.yarn_factor > 0:
+        freqs, mscale = yarn_inv_freq(cfg, hd), yarn_rope_mscale(cfg)
+    else:
+        freqs = jnp.exp(-math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half)
+        mscale = 1.0
     angles = positions[..., None].astype(jnp.float32) * freqs   # (..., seq, half)
     # sin/cos tables in f32 (cheap, (S, half)); the rotation multiplies stay
     # in x's dtype so fwd/bwd chains on (B,S,H,hd) are bf16 (§Perf iter 2)
     dt = jnp.float32 if _F32_CHAINS else x.dtype
-    cos = jnp.cos(angles)[..., None, :].astype(dt)
-    sin = jnp.sin(angles)[..., None, :].astype(dt)
+
+    def table(f):
+        t = f(angles)
+        return (t * mscale if mscale != 1.0 else t)[..., None, :].astype(dt)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = x[..., :half].astype(dt), x[..., half:].astype(dt)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

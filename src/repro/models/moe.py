@@ -19,6 +19,11 @@ Two executable strategies (mirroring the paper's naive-vs-combined framing):
 
 Both run inside ``shard_map`` over the expert ('model') axis and are
 numerically identical up to capacity drops.
+
+A device may hold only some of the router's experts (``num_held_experts``,
+one chip's share of a wider expert-parallel layer): the one-device layer
+then routes over all of them and returns the held experts' part, the same
+function (:func:`held_experts_part`) the replicated strategy runs per shard.
 """
 from __future__ import annotations
 
@@ -46,8 +51,10 @@ def init_dense_ffn(pb: ParamBuilder, cfg: ModelConfig, d_ff: Optional[int] = Non
 
 
 def init_moe(pb: ParamBuilder, cfg: ModelConfig) -> None:
-    D, E, F = cfg.d_model, cfg.num_experts, cfg.d_ff_expert
-    pb.param("router", (D, E), ("embed", None), scale=D)
+    """The router over all ``num_experts``; the weights of the held ones."""
+    D, F = cfg.d_model, cfg.d_ff_expert
+    pb.param("router", (D, cfg.num_experts), ("embed", None), scale=D)
+    E = cfg.experts_held
     pb.param("we_gate", (E, D, F), ("expert", "embed", "mlp"), scale=D)
     pb.param("we_up", (E, D, F), ("expert", "embed", "mlp"), scale=D)
     pb.param("we_down", (E, F, D), ("expert", "mlp", "embed"), scale=F)
@@ -80,36 +87,88 @@ def dense_ffn(p: Dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def route(p: Dict, cfg: ModelConfig, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Token -> (top-k expert ids, gate weights). x: (T, D) flattened tokens."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    if cfg.num_padded_experts:
-        pad = jnp.arange(cfg.num_experts) >= cfg.num_experts - cfg.num_padded_experts
-        logits = jnp.where(pad, -1e30, logits)
-    scores = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(scores, cfg.moe_top_k)        # (T, k)
-    if cfg.norm_topk_prob:
-        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    return top_e.astype(jnp.int32), top_w
+    """Token -> (top-k expert ids, gate weights). x: (T, D) flattened tokens.
+
+    Softmax over every expert in float32.  With ``n_group`` > 1 (DeepSeek-
+    V2's group-limited greedy), a group scores its best expert, the
+    ``topk_group`` best groups are kept and the top-k is taken among their
+    experts.  The weights are then renormalised (``norm_topk_prob``) or
+    scaled by ``routed_scaling_factor``."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        if cfg.num_padded_experts:
+            pad = jnp.arange(cfg.num_experts) >= cfg.num_experts - cfg.num_padded_experts
+            logits = jnp.where(pad, -1e30, logits)
+        scores = jax.nn.softmax(logits, axis=-1)
+        if cfg.n_group > 1:
+            T = scores.shape[0]
+            grouped = scores.reshape(T, cfg.n_group, -1)
+            _, keep = jax.lax.top_k(grouped.max(-1), cfg.topk_group)   # (T, g)
+            kept = jnp.zeros((T, cfg.n_group), bool).at[
+                jnp.arange(T)[:, None], keep].set(True)
+            scores = jnp.where(kept[:, :, None], grouped, 0.0).reshape(T, -1)
+        top_w, top_e = jax.lax.top_k(scores, cfg.moe_top_k)        # (T, k)
+        if cfg.norm_topk_prob:
+            top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        elif cfg.routed_scaling_factor != 1.0:
+            top_w = top_w * cfg.routed_scaling_factor
+        return top_e.astype(jnp.int32), top_w
+
+
+def row_route_counts(cfg: ModelConfig, top_e: jnp.ndarray, B: int) -> jnp.ndarray:
+    """(B, 1 + held) int32 per batch row: the (token, expert) pairs routed,
+    then those routed to each held expert.  top_e: (B * L, k)."""
+    local = top_e.reshape(B, -1) - cfg.first_held_expert
+    held = jax.nn.one_hot(local, cfg.experts_held, dtype=jnp.int32).sum(1)
+    routed = jnp.full((B, 1), local.shape[1], jnp.int32)
+    return jnp.concatenate([routed, held], axis=1)
 
 
 def _expert_compute(cfg: ModelConfig, p: Dict, xbuf: jnp.ndarray,
-                    group_sizes: jnp.ndarray, *, local_slice=None) -> jnp.ndarray:
-    """Grouped SwiGLU over sorted token buffer via lax.ragged_dot.
+                    group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """Grouped SwiGLU over a sorted token buffer via lax.ragged_dot.
 
-    xbuf: (C, D) tokens sorted by expert; group_sizes: (E_local,).
-    local_slice: optional (start, size) to slice the expert dim of weights
-    (used inside shard_map where weights arrive already sliced)."""
+    xbuf: (C, D) tokens sorted by expert; group_sizes: (E_local,), summing
+    to at most C (rows past the last group are not any expert's)."""
     wg, wu, wd = p["we_gate"], p["we_up"], p["we_down"]
-    if local_slice is not None:
-        s, n = local_slice
-        wg = jax.lax.dynamic_slice_in_dim(wg, s, n, 0)
-        wu = jax.lax.dynamic_slice_in_dim(wu, s, n, 0)
-        wd = jax.lax.dynamic_slice_in_dim(wd, s, n, 0)
     dt = xbuf.dtype
     h = jax.nn.silu(jax.lax.ragged_dot(xbuf, wg.astype(dt), group_sizes)) \
         * jax.lax.ragged_dot(xbuf, wu.astype(dt), group_sizes)
     return jax.lax.ragged_dot(h, wd.astype(dt), group_sizes)
+
+
+def held_experts_part(p: Dict, cfg: ModelConfig, xf: jnp.ndarray,
+                      top_e: jnp.ndarray, top_w: jnp.ndarray, e0,
+                      n_held: int, capacity: Optional[int] = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """What experts e0 .. e0 + n_held - 1 (whose weights ``p`` holds) add
+    to each token: gate weight x expert SwiGLU, summed over the token's
+    pairs routed to them.  Pairs routed to other experts add nothing.
+
+    xf: (T, D); top_e, top_w: (T, k) over the router's experts.  With
+    ``capacity`` at most that many pairs are computed (the rest dropped);
+    without, every pair is kept.  Returns (out (T, D), per-held-expert
+    pairs computed (n_held,), pairs of held experts dropped)."""
+    with jax.named_scope("moe.experts"):
+        k = top_e.shape[-1]
+        local_e = top_e.reshape(-1) - e0
+        is_mine = (local_e >= 0) & (local_e < n_held)
+        sort_key = jnp.where(is_mine, local_e, n_held)          # others last
+        order = jnp.argsort(sort_key)
+        if capacity is not None:
+            order = order[:capacity]
+        C = order.shape[0]
+        tok = order // k
+        gs = jnp.bincount(sort_key, length=n_held + 1)[:n_held]
+        gs = jnp.diff(jnp.minimum(jnp.cumsum(gs), C), prepend=0)
+        out_buf = _expert_compute(cfg, p, xf[tok], gs)
+        kept = jnp.arange(C) < gs.sum()                         # held, in capacity
+        w = top_w.reshape(-1)[order].astype(out_buf.dtype)
+        contrib = jnp.where(kept[:, None], out_buf * w[:, None], 0)
+        out = jnp.zeros_like(xf).at[tok].add(contrib)
+        dropped = (is_mine.sum() - kept.sum()).astype(jnp.int32)
+        return out, gs.astype(jnp.int32), dropped
 
 
 # ---------------------------------------------------------------------------
@@ -117,21 +176,16 @@ def _expert_compute(cfg: ModelConfig, p: Dict, xbuf: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def moe_ffn_local(p: Dict, cfg: ModelConfig, x: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
-    """MoE forward on one device: sort-by-expert + ragged grouped matmul."""
+    """MoE forward on one device: route over all the router's experts,
+    compute the held experts' part with no pair dropped (every expert, when
+    ``num_held_experts`` is 0), add the shared experts."""
     B, S, D = x.shape
     xf = x.reshape(-1, D)
-    T = xf.shape[0]
-    k, E = cfg.moe_top_k, cfg.num_experts
     top_e, top_w = route(p, cfg, xf)                            # (T,k)
-    flat_e = top_e.reshape(-1)                                  # (T*k,)
-    order = jnp.argsort(flat_e)                                 # sort by expert
-    tok = order // k
-    xbuf = xf[tok]                                              # (T*k, D)
-    gs = jnp.bincount(flat_e, length=E)                         # group sizes
-    out_buf = _expert_compute(cfg, p, xbuf, gs)                 # (T*k, D)
-    w = top_w.reshape(-1)[order].astype(out_buf.dtype)          # gate weights
-    out = jnp.zeros_like(xf).at[tok].add(out_buf * w[:, None])
-    stats = {"expert_load": gs, "dropped": jnp.zeros((), jnp.int32)}
+    out, _, dropped = held_experts_part(p, cfg, xf, top_e, top_w,
+                                        cfg.first_held_expert, cfg.experts_held)
+    load = jnp.bincount(top_e.reshape(-1), length=cfg.num_experts)
+    stats = {"expert_load": load, "dropped": dropped}
     if cfg.num_shared_experts > 0:
         out = out + dense_ffn(p["shared"], cfg, x).reshape(-1, D)
     return out.reshape(B, S, D), stats
@@ -167,8 +221,8 @@ def moe_ffn_replicated(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
     B, S, D = x.shape
     E = cfg.num_experts
     P = mesh.shape[axis_name]
-    assert E % P == 0, (E, P)
-    E_local = E // P
+    assert cfg.experts_held % P == 0, (cfg.experts_held, P)
+    E_local = cfg.experts_held // P
     batch_axes = _divisible_batch_axes(mesh, batch_axes, B)
     Pspec = jax.sharding.PartitionSpec
 
@@ -176,36 +230,17 @@ def moe_ffn_replicated(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
         pl = {"router": router, "we_gate": wg, "we_up": wu, "we_down": wd}
         Bl, Sl = xl.shape[0], xl.shape[1]
         xf = xl.reshape(-1, D)
-        T = Bl * Sl
-        C = _capacity(cfg, T, P)
+        C = _capacity(cfg, Bl * Sl, P)
         top_e, top_w = route(pl, cfg, xf)                       # identical on all shards
-        e0 = jax.lax.axis_index(axis_name) * E_local
-        flat_e = top_e.reshape(-1)
-        local_e = flat_e - e0
-        is_mine = (local_e >= 0) & (local_e < E_local)
-        sort_key = jnp.where(is_mine, local_e, E_local)         # sentinel last
-        order = jnp.argsort(sort_key)[:C]                       # capacity-bounded
-        tok = order // cfg.moe_top_k
-        xbuf = xf[tok]
-        kept = is_mine[order]
-        gs_full = jnp.bincount(jnp.where(is_mine, local_e, E_local), length=E_local + 1)
-        taken = jnp.minimum(jnp.cumsum(gs_full[:E_local]), C)
-        gs = jnp.diff(taken, prepend=0)
-        gs = jnp.concatenate([gs, jnp.array([C], gs.dtype) - gs.sum()[None]])
-        wd_pad = jnp.concatenate([wd, jnp.zeros_like(wd[:1])], 0)
-        wg_pad = jnp.concatenate([wg, jnp.zeros_like(wg[:1])], 0)
-        wu_pad = jnp.concatenate([wu, jnp.zeros_like(wu[:1])], 0)
-        pl_pad = {"we_gate": wg_pad, "we_up": wu_pad, "we_down": wd_pad}
-        out_buf = _expert_compute(cfg, pl_pad, xbuf, gs)
-        w = top_w.reshape(-1)[order].astype(out_buf.dtype) * kept.astype(out_buf.dtype)
-        out = jnp.zeros_like(xf).at[tok].add(out_buf * w[:, None])
+        e0 = cfg.first_held_expert + jax.lax.axis_index(axis_name) * E_local
+        out, gs, dropped = held_experts_part(pl, cfg, xf, top_e, top_w, e0,
+                                             E_local, capacity=C)
         out = jax.lax.psum(out, axis_name)                      # the monoid combine
         stat_axes = (axis_name,) + batch_axes                   # total over fleet
         load = jax.lax.psum(
-            jnp.zeros((E,), jnp.int32).at[e0 + jnp.arange(E_local)].set(
-                gs[:E_local].astype(jnp.int32)), stat_axes)
-        dropped = jax.lax.psum(
-            (is_mine.sum() - kept.sum()).astype(jnp.int32), stat_axes)
+            jnp.zeros((E,), jnp.int32).at[e0 + jnp.arange(E_local)].set(gs),
+            stat_axes)
+        dropped = jax.lax.psum(dropped, stat_axes)
         return out.reshape(Bl, Sl, D), load, dropped
 
     xspec = Pspec(batch_axes if batch_axes else None)
@@ -244,6 +279,7 @@ def moe_ffn_a2a(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
     E = cfg.num_experts
     P = mesh.shape[axis_name]
     assert E % P == 0, (E, P)
+    assert cfg.experts_held == E, "a2a dispatch needs every expert held"
     if S % P != 0:
         return moe_ffn_replicated(p, cfg, x, mesh, axis_name=axis_name,
                                   batch_axes=batch_axes)
@@ -332,7 +368,7 @@ def moe_ffn(p: Dict, cfg: ModelConfig, x: jnp.ndarray, *, mesh=None,
     """Dispatch to the configured expert-parallel strategy."""
     if mesh is None or axis_name not in getattr(mesh, "shape", {}) \
             or mesh.shape.get(axis_name, 1) == 1 \
-            or cfg.num_experts % max(mesh.shape.get(axis_name, 1), 1) != 0:
+            or cfg.experts_held % max(mesh.shape.get(axis_name, 1), 1) != 0:
         return moe_ffn_local(p, cfg, x)
     if impl == "replicated":
         return moe_ffn_replicated(p, cfg, x, mesh, axis_name=axis_name)

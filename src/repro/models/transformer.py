@@ -168,9 +168,13 @@ class RunCtx:
 
 
 def _apply_ffn(p: Dict, cfg: ModelConfig, ftype: str, x: jnp.ndarray,
-               ctx: RunCtx, stats: Optional[Dict]):
+               ctx: RunCtx, stats: Optional[Dict],
+               counts: Optional[jnp.ndarray] = None):
+    """-> (x, stats, counts).  A MoE layer adds its router statistics to
+    ``stats`` and, where the decode path passes them, each batch row's
+    routed pairs to ``counts`` (:func:`moe.row_route_counts`)."""
     if ftype == "none":
-        return x, stats
+        return x, stats, counts
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     if ftype == "dense":
         out = moe_mod.dense_ffn(p["ffn"], cfg, h)
@@ -178,7 +182,12 @@ def _apply_ffn(p: Dict, cfg: ModelConfig, ftype: str, x: jnp.ndarray,
         out, s = moe_mod.moe_ffn(p["ffn"], cfg, h, mesh=ctx.mesh, impl=ctx.moe_impl)
         if stats is not None:
             stats = jax.tree_util.tree_map(jnp.add, stats, s)
-    return x + out, stats
+        if counts is not None:
+            # the layer's own routing, recomputed on the same input: XLA
+            # computes it once for both
+            top_e, _ = moe_mod.route(p["ffn"], cfg, h.reshape(-1, h.shape[-1]))
+            counts = counts + moe_mod.row_route_counts(cfg, top_e, h.shape[0])
+    return x + out, stats, counts
 
 
 def _apply_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
@@ -210,7 +219,8 @@ def _apply_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
         h = rms_norm(x, p["norm_x"], cfg.norm_eps)
         kv = attn.cross_kv(p["xattn"], cfg, context)
         x = x + attn.cross_attention(p["xattn"], cfg, h, kv)
-    return _apply_ffn(p, cfg, ftype, x, ctx, stats)
+    x, stats, _ = _apply_ffn(p, cfg, ftype, x, ctx, stats)
+    return x, stats
 
 
 def _zero_stats(cfg: ModelConfig) -> Optional[Dict]:
@@ -353,6 +363,19 @@ def loss_fn(params: Pytree, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
 # decode (serving)
 # ---------------------------------------------------------------------------
 
+# A model with MoE layers keeps, beside its decode caches, each batch row's
+# running routing counts: (B, 1 + experts_held) int32, the (token, expert)
+# pairs routed in every MoE layer, then those routed to each held expert
+# (``moe.row_route_counts``).  A Sum monoid: every decode_step adds the
+# pairs of its L tokens, so an extend and L one-token steps agree.  The
+# serving engine reads it under the same name (``runtime.engine.ROUTE_KEY``).
+ROUTE_KEY = "route"
+
+
+def _has_moe(cfg: ModelConfig) -> bool:
+    return any(cfg.ffn_type(i) == "moe" for i in range(cfg.num_layers))
+
+
 def _init_layer_cache(cfg: ModelConfig, ltype: str, batch: int, max_seq: int,
                       params: Optional[Dict] = None,
                       context: Optional[jnp.ndarray] = None):
@@ -419,6 +442,8 @@ def init_cache(params: Pytree, cfg: ModelConfig, batch: int, max_seq: int, *,
     prelude, period_slots, remainder = _layer_plan(cfg)
     pos0 = jnp.zeros((batch,) if pos_per_slot else (), jnp.int32)
     cache: Dict[str, Any] = {"pos": pos0}
+    if _has_moe(cfg):
+        cache[ROUTE_KEY] = jnp.zeros((batch, 1 + cfg.experts_held), jnp.int32)
     for i, (lt, _) in enumerate(prelude):
         cache[f"prelude_{i}"] = _init_layer_cache(
             cfg, lt, batch, max_seq, params[f"prelude_{i}"], context)
@@ -458,19 +483,21 @@ def _extends_in_parallel(ltype: str, ctx: RunCtx) -> bool:
 
 def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
                   x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
-                  ctx: RunCtx):
+                  ctx: RunCtx, counts: Optional[jnp.ndarray]):
+    """-> (x, cache, counts): see :func:`_apply_ffn` for ``counts``."""
     L = x.shape[1]
     if L > 1 and not _extends_in_parallel(ltype, ctx):
         # scan the L columns one token at a time through this layer
-        def column(c, inp):
+        def column(carry, inp):
+            c, n = carry
             xj, j = inp
-            y, c = _decode_layer(p, cfg, ltype, ftype, xj[:, None], c,
-                                 pos + j, ctx)
-            return c, y[:, 0]
+            y, c, n = _decode_layer(p, cfg, ltype, ftype, xj[:, None], c,
+                                    pos + j, ctx, n)
+            return (c, n), y[:, 0]
 
-        cache, ys = jax.lax.scan(
-            column, cache, (jnp.swapaxes(x, 0, 1), jnp.arange(L)))
-        return jnp.swapaxes(ys, 0, 1), cache
+        (cache, counts), ys = jax.lax.scan(
+            column, (cache, counts), (jnp.swapaxes(x, 0, 1), jnp.arange(L)))
+        return jnp.swapaxes(ys, 0, 1), cache, counts
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if ltype in ("attn", "local"):
         window = cfg.sliding_window if ltype == "local" else None
@@ -504,8 +531,8 @@ def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
     if cfg.decoder_cross_attn and ltype in ("attn", "local") and "xk" in cache:
         h = rms_norm(x, p["norm_x"], cfg.norm_eps)
         x = x + attn.cross_attention(p["xattn"], cfg, h, (cache["xk"], cache["xv"]))
-    x, _ = _apply_ffn(p, cfg, ftype, x, ctx, None)
-    return x, cache
+    x, _, counts = _apply_ffn(p, cfg, ftype, x, ctx, None, counts)
+    return x, cache, counts
 
 
 def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
@@ -520,7 +547,9 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
     parallel pass, and layers that cannot (see :func:`_extends_in_parallel`)
     scan the L columns.  Only the last column is unembedded, so an extend
     never holds (B, L, V) logits.  Rows that would fall past the cache's
-    end are dropped (:func:`attention.cache_span_update`).
+    end are dropped (:func:`attention.cache_span_update`).  A model with
+    MoE layers adds the step's routed pairs to each row's ``ROUTE_KEY``
+    counts, summed through the layer scan.
     """
     B, L = tokens.shape
     pos = cache["pos"]
@@ -529,28 +558,35 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
         x = x * math.sqrt(cfg.d_model)
     x = shd.act(x, ("batch", None, "embed"))
     new_cache: Dict[str, Any] = {"pos": pos + L}
+    counts = jnp.zeros_like(cache[ROUTE_KEY]) if ROUTE_KEY in cache else None
 
     prelude, period_slots, remainder = _layer_plan(cfg)
     for i, (lt, ft) in enumerate(prelude):
-        x, new_cache[f"prelude_{i}"] = _decode_layer(
-            params[f"prelude_{i}"], cfg, lt, ft, x, cache[f"prelude_{i}"], pos, ctx)
+        x, new_cache[f"prelude_{i}"], counts = _decode_layer(
+            params[f"prelude_{i}"], cfg, lt, ft, x, cache[f"prelude_{i}"], pos,
+            ctx, counts)
 
     if period_slots:
-        def body(x, inp):
+        def body(carry, inp):
+            x, counts = carry
             slot_params, slot_cache = inp
             new_slots = {}
             for j, (lt, ft) in enumerate(period_slots):
-                x, new_slots[f"slot_{j}"] = _decode_layer(
+                x, new_slots[f"slot_{j}"], counts = _decode_layer(
                     slot_params[f"slot_{j}"], cfg, lt, ft, x,
-                    slot_cache[f"slot_{j}"], pos, ctx)
-            return x, new_slots
+                    slot_cache[f"slot_{j}"], pos, ctx, counts)
+            return (x, counts), new_slots
 
-        x, new_layer_cache = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        (x, counts), new_layer_cache = jax.lax.scan(
+            body, (x, counts), (params["layers"], cache["layers"]))
         new_cache["layers"] = new_layer_cache
 
     for i, (lt, ft) in enumerate(remainder):
-        x, new_cache[f"rem_{i}"] = _decode_layer(
-            params[f"rem_{i}"], cfg, lt, ft, x, cache[f"rem_{i}"], pos, ctx)
+        x, new_cache[f"rem_{i}"], counts = _decode_layer(
+            params[f"rem_{i}"], cfg, lt, ft, x, cache[f"rem_{i}"], pos, ctx,
+            counts)
+    if counts is not None:
+        new_cache[ROUTE_KEY] = cache[ROUTE_KEY] + counts
 
     if L > 1:
         x = x[:, -1:]

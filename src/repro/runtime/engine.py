@@ -79,6 +79,12 @@ from .prefix_cache import PrefixCache, PrefixCacheConfig, PrefixHit
 # stop condition as a summed indicator (eos_hits > 0 <=> OR of eos hits)
 METRIC_COLS = ("logprob_sum", "tokens", "eos_hits")
 
+# the cache leaf in which a mixture-of-experts backend keeps each row's
+# running routing counts (``models.transformer.ROUTE_KEY``)
+ROUTE_KEY = "route"
+# the cache leaves that are not KV rows: positions and route counts
+_NOT_KV = ("pos", ROUTE_KEY)
+
 
 def decode_metrics_init(num_slots: int) -> jnp.ndarray:
     """The identity table: (num_slots, len(METRIC_COLS)) float32 zeros."""
@@ -310,7 +316,12 @@ class EngineBackend:
     ``init_cache(batch, pos_per_slot)`` builds a fresh cache pytree whose
     leaves carry the batch dim at axis 0 (axis 1 under the ``stacked_key``
     subtree) plus a ``pos`` leaf — scalar, or ``(batch,)`` when
-    ``pos_per_slot`` (the rolling cache).
+    ``pos_per_slot`` (the rolling cache).  A mixture-of-experts model may
+    add a ``route`` leaf (:data:`ROUTE_KEY`): ``(batch, 1 + held)`` int32
+    running sums of each row's routed (token, expert) pairs, then of those
+    routed to each expert it holds; the engine adds each decode step's
+    growth on the occupied slots to :class:`EngineStats` (``routed_pairs``,
+    ``held_pairs``, ``held_expert_max``).
     """
 
     decode: Callable[[Any, Any, jnp.ndarray], Tuple[jnp.ndarray, Any]]
@@ -350,6 +361,11 @@ class EngineStats:
     slot_steps: int = 0           # occupied slots summed over decode steps
     prefix_upload_bytes: int = 0      # prefix KV slabs sent to the device
     prefix_download_bytes: int = 0    # prefix KV rows fetched for the trie
+    # mixture-of-experts backends (a ``ROUTE_KEY`` cache leaf), over the
+    # occupied slots of every decode step, all MoE layers summed:
+    routed_pairs: int = 0         # (token, expert) pairs routed
+    held_pairs: int = 0           # of those, routed to an expert held here
+    held_expert_max: int = 0      # the most pairs any one held expert took
 
 
 @dataclasses.dataclass
@@ -424,6 +440,7 @@ class ContinuousEngine:
         place = backend.place if backend.place is not None else (lambda x: x)
         self._cache = place(backend.init_cache(config.num_slots, True))
         self._table = place(decode_metrics_init(config.num_slots))
+        self._held_pairs: Optional[np.ndarray] = None   # per held expert
         # -- radix prefix KV cache (runtime/prefix_cache.py) ----------------
         self.prefix: Optional[PrefixCache] = None
         self._slab = 0
@@ -440,7 +457,7 @@ class ContinuousEngine:
             # and sequence axes per leaf — the host-side (dis)assembly spec
             # for prefix slabs (the trie stores opaque per-leaf numpy blocks)
             tmpl = jax.eval_shape(lambda: backend.init_cache(1, True))
-            kv_tmpl = {k: v for k, v in tmpl.items() if k != "pos"}
+            kv_tmpl = {k: v for k, v in tmpl.items() if k not in _NOT_KV}
             leaves, treedef = jax.tree_util.tree_flatten_with_path(kv_tmpl)
             self._kv_treedef = treedef
             self._kv_shapes = [tuple(leaf.shape) for _, leaf in leaves]
@@ -477,11 +494,18 @@ class ContinuousEngine:
             return jax.vmap(one)(seeds, tok_idx, logits).astype(jnp.int32)
 
         def step_impl(params, cache, cur, active, seeds, tok_idx, table):
+            before = cache.get(ROUTE_KEY)
             logits, cache = decode(params, cache, cur)
             sampled = sample_rows(logits, seeds, tok_idx)
             rows = metric_rows(logits, sampled, eos)
             table = fold_decode_metrics(
                 table, rows, jnp.arange(S, dtype=jnp.int32), active, S)
+            if before is not None:
+                # the step's route counts over the occupied slots ride in
+                # the one transfer the step makes, after the sampled ids
+                grown = jnp.where(active[:, None],
+                                  cache[ROUTE_KEY] - before, 0).sum(0)
+                sampled = jnp.concatenate([sampled, grown.astype(jnp.int32)])
             return cache, sampled, table
 
         self._step_fn = jax.jit(step_impl, donate_argnums=(1,))
@@ -493,7 +517,8 @@ class ContinuousEngine:
             """Scatter cached prefix KV rows into a fresh k-row prefill
             cache (rows beyond each request's prefix are zeros over zeros)
             and start each row's position at its prefix length."""
-            kv = {key: val for key, val in cachek.items() if key != "pos"}
+            kv = {key: val for key, val in cachek.items()
+                  if key not in _NOT_KV}
             slabs = jax.tree_util.tree_unflatten(kv_treedef, prefix_leaves)
 
             def put(path, big, small):
@@ -504,6 +529,8 @@ class ContinuousEngine:
 
             kv = jax.tree_util.tree_map_with_path(put, kv, slabs)
             kv["pos"] = jnp.asarray(prefix_len, cachek["pos"].dtype)
+            if ROUTE_KEY in cachek:
+                kv[ROUTE_KEY] = cachek[ROUTE_KEY]
             return kv
 
         pad = cfg.pad_id
@@ -593,7 +620,7 @@ class ContinuousEngine:
         def make_gather(k: int):
             def gather_impl(cachek):
                 kv = {key: val for key, val in cachek.items()
-                      if key != "pos"}
+                      if key not in _NOT_KV}
 
                 def take(path, leaf):
                     keys = [getattr(e, "key", None) for e in path]
@@ -945,6 +972,8 @@ class ContinuousEngine:
 
         with spans.span("engine.wait_tokens", wait=True):
             sampled_np = np.asarray(jax.device_get(sampled))
+        if sampled_np.shape[0] > S:
+            self._count_routes(sampled_np[S:])
         now = self._clock()
         retired = []
         for i in occupied:
@@ -961,6 +990,16 @@ class ContinuousEngine:
                 retired.append(i)
         if retired:
             self._retire(retired, events, now)
+
+    def _count_routes(self, grown: np.ndarray) -> None:
+        """One decode step's route counts: pairs routed, then per held
+        expert (see :data:`ROUTE_KEY`)."""
+        if self._held_pairs is None:
+            self._held_pairs = np.zeros(grown.shape[0] - 1, np.int64)
+        self._held_pairs += grown[1:]
+        self.stats.routed_pairs += int(grown[0])
+        self.stats.held_pairs += int(grown[1:].sum())
+        self.stats.held_expert_max = int(self._held_pairs.max())
 
     def _dispatch(self, events: List[StreamEvent]) -> List[StreamEvent]:
         with spans.span("engine.dispatch_events"):
