@@ -41,8 +41,7 @@ def program_config(config: dict):
     """The program's model configuration, held to the configuration file.
 
     The keys the file lists under ``reduced`` (the depth and the experts
-    held on this chip) and the deployment's first held expert are set on
-    the registered architecture; every key is then compared, and any
+    held on this chip) are set on the registered architecture; every key is then compared, and any
     difference raises, as does a program without group-limited routing,
     held experts or YaRN."""
     from repro.configs import get_config
@@ -50,7 +49,7 @@ def program_config(config: dict):
     cfg = get_config(config["program_arch"],
                      smoke=bool(config.get("program_smoke", False)))
     missing = [f for f in list(FIELDS) + list(YARN) + list(SHARE)
-               + ["first_held_expert"] if not hasattr(cfg, f)]
+               if not hasattr(cfg, f)]
     if missing:
         raise ValueError(f"{config['program_arch']}: the program has no "
                          f"{missing}: it cannot route by groups over a "
@@ -61,9 +60,7 @@ def program_config(config: dict):
     if unknown:
         raise ValueError(f"{config['program_arch']}: the program has no "
                          f"setting for the reduced keys {unknown}")
-    cfg = dataclasses.replace(
-        cfg, first_held_expert=config["deployment"]["first_held_expert"],
-        **{field[k]: config[k] for k in reduced})
+    cfg = dataclasses.replace(cfg, **{field[k]: config[k] for k in reduced})
     want = {p: config[k] for p, k in FIELDS.items()}
     want.update({p: config["rope_scaling"][k] for p, k in YARN.items()})
     want.update({p: config["deployment"][k] for p, k in SHARE.items()})

@@ -37,8 +37,8 @@ mscale(s, m) = 0.1 m ln s + 1.
 
 One chip's share (the configuration's ``deployment``): the router keeps all
 ``router_experts`` outputs and its experts per token; this chip holds
-``n_routed_experts`` of them from ``first_held_expert`` on, and a token's
-pairs routed to any other expert add nothing here, as in the program.  The
+the first ``n_routed_experts`` of them, and a token's pairs routed to any
+other expert add nothing here, as in the program.  The
 shared experts and the whole vocabulary are held.
 
 Every matrix product runs at ``Precision.HIGHEST`` in float32.  Departures
@@ -108,13 +108,11 @@ class Sizes:
     mscale: float
     mscale_all_dim: float
     router_experts: int            # the router's outputs
-    first_held_expert: int
 
     @classmethod
     def from_config(cls, config: dict) -> "Sizes":
         merged = dict(config, **config["rope_scaling"],
-                      **{k: config["deployment"][k]
-                         for k in ("router_experts", "first_held_expert")})
+                      router_experts=config["deployment"]["router_experts"])
         return cls(**{f.name: merged[f.name] for f in dataclasses.fields(cls)})
 
     @property
@@ -344,11 +342,10 @@ def gates(h, router, s: Sizes, fp8: bool = False) -> jnp.ndarray:
 
 def held_routed(h, w, s: Sizes, fp8: bool, first: int, count: int):
     """What routed experts first .. first + count - 1 add: each token's gate
-    for an expert times that expert's SwiGLU.  ``w["we_*"]`` hold the
-    experts from ``s.first_held_expert`` on."""
+    for an expert times that expert's SwiGLU.  ``w["we_*"]`` hold experts
+    0 .. ``s.n_routed_experts`` - 1."""
     g = gates(h, w["router"], s, fp8)[:, first:first + count]     # (S, count)
-    held = slice(first - s.first_held_expert,
-                 first - s.first_held_expert + count)
+    held = slice(first, first + count)
     a = _mm(h, w["we_gate"][held], fp8, "sd,edf->esf")
     u = _mm(h, w["we_up"][held], fp8, "sd,edf->esf")
     y = _mm(jax.nn.silu(a) * u, w["we_down"][held], fp8, "esf,efd->esd")
@@ -358,8 +355,7 @@ def held_routed(h, w, s: Sizes, fp8: bool, first: int, count: int):
 def moe_layer(h, w, s: Sizes, fp8: bool = False):
     """This chip's MoE layer: its held experts' part plus the shared
     experts."""
-    return held_routed(h, w, s, fp8, s.first_held_expert,
-                       s.n_routed_experts) \
+    return held_routed(h, w, s, fp8, 0, s.n_routed_experts) \
         + _swiglu(h, w["shared_w_gate"], w["shared_w_up"],
                   w["shared_w_down"], fp8)
 

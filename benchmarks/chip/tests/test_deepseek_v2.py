@@ -136,7 +136,7 @@ def test_chip_shares_add_up_to_the_uncut_reference_layer():
     routed parts of 4 chips holding 4 experts each, plus the shared
     experts counted once, are its uncut layer."""
     config = dict(fixture("deepseek-v2-smoke.json"), n_routed_experts=16,
-                  deployment={"router_experts": 16, "first_held_expert": 0})
+                  deployment={"router_experts": 16})
     s = ref.Sizes.from_config(config)
     w = jax.tree_util.tree_map(lambda a: a[0].astype(jnp.float32),
                                ref.make_weights(9, s)["moe"])
@@ -146,17 +146,25 @@ def test_chip_shares_add_up_to_the_uncut_reference_layer():
     parts = sum(ref.held_routed(h, w, s, False, 4 * c, 4) for c in range(4))
     np.testing.assert_allclose(np.asarray(parts + shared), np.asarray(whole),
                                atol=1e-5, rtol=1e-5)
-    # the program's layer over the same share agrees with the reference's
+    # the program's part of the same share agrees with the reference's
     from repro.models import moe as M
     cfg = dataclasses.replace(program.program_config(
         fixture("deepseek-v2-smoke.json")), dtype=jnp.float32,
-        num_held_experts=4, first_held_expert=8)
+        num_held_experts=4)
     held = {"router": w["router"], "we_gate": w["we_gate"][8:12],
-            "we_up": w["we_up"][8:12], "we_down": w["we_down"][8:12],
-            "shared": {k: w["shared_" + k] for k in
-                       ("w_gate", "w_up", "w_down")}}
-    got, _ = M.moe_ffn_local(held, cfg, h[None])
-    want = ref.held_routed(h, w, s, False, 8, 4) + shared
+            "we_up": w["we_up"][8:12], "we_down": w["we_down"][8:12]}
+    e, g = M.route(held, cfg, h)
+    got, _, _ = M.held_experts_part(held, cfg, h, e, g, 8, 4)
+    want = ref.held_routed(h, w, s, False, 8, 4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    # and its whole layer holding experts 0-3, shared experts included
+    first = {"router": w["router"], "we_gate": w["we_gate"][:4],
+             "we_up": w["we_up"][:4], "we_down": w["we_down"][:4],
+             "shared": {k: w["shared_" + k] for k in
+                        ("w_gate", "w_up", "w_down")}}
+    got, _ = M.moe_ffn_local(first, cfg, h[None])
+    want = ref.held_routed(h, w, s, False, 0, 4) + shared
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 
@@ -172,8 +180,8 @@ def test_fp8_control_departs_from_float32(smoke):
 def test_the_configuration_file_is_the_published_share():
     config = json.loads(CONFIG.read_text())
     cfg = program.program_config(config)
-    assert (cfg.num_layers, cfg.num_experts, cfg.experts_held,
-            cfg.first_held_expert) == (5, 160, 10, 0)
+    assert (cfg.num_layers, cfg.num_experts, cfg.experts_held) == (5, 160,
+                                                                  10)
     assert sorted(config["reduced"]) == ["n_routed_experts",
                                          "num_hidden_layers"]
     assert config["published"] == {"num_hidden_layers": 60,

@@ -65,10 +65,9 @@ class ModelConfig:
     topk_group: int = 1
     routed_scaling_factor: float = 1.0   # gate weights x this when not normed
     # one device's share of an expert-parallel layer: it holds experts
-    # first_held_expert .. + num_held_experts - 1 of the router's
-    # num_experts (0 = all); pairs routed elsewhere add nothing here
+    # 0 .. num_held_experts - 1 of the router's num_experts (0 = all);
+    # pairs routed elsewhere add nothing here
     num_held_experts: int = 0
-    first_held_expert: int = 0
     # -- MLA (DeepSeek-V2)
     use_mla: bool = False
     q_lora_rank: int = 0
@@ -115,8 +114,8 @@ class ModelConfig:
         if self.num_experts:
             assert self.num_experts % self.n_group == 0, (
                 "experts must split into equal routing groups")
-            assert self.first_held_expert + self.experts_held \
-                <= self.num_experts, "held experts beyond the router"
+            assert self.experts_held <= self.num_experts, (
+                "held experts beyond the router")
 
     @property
     def experts_held(self) -> int:

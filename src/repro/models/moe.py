@@ -116,12 +116,17 @@ def route(p: Dict, cfg: ModelConfig, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.n
         return top_e.astype(jnp.int32), top_w
 
 
+# the decode-cache leaf in which a model with MoE layers hands the serving
+# engine each row's ``row_pairs`` of its last decode step
+ROUTE_KEY = "route"
+
+
 def row_route_counts(cfg: ModelConfig, top_e: jnp.ndarray, B: int) -> jnp.ndarray:
     """(B, 1 + held) int32 per batch row: the (token, expert) pairs routed,
     then those routed to each held expert.  top_e: (B * L, k)."""
-    local = top_e.reshape(B, -1) - cfg.first_held_expert
-    held = jax.nn.one_hot(local, cfg.experts_held, dtype=jnp.int32).sum(1)
-    routed = jnp.full((B, 1), local.shape[1], jnp.int32)
+    top_e = top_e.reshape(B, -1)
+    held = jax.nn.one_hot(top_e, cfg.experts_held, dtype=jnp.int32).sum(1)
+    routed = jnp.full((B, 1), top_e.shape[1], jnp.int32)
     return jnp.concatenate([routed, held], axis=1)
 
 
@@ -182,10 +187,11 @@ def moe_ffn_local(p: Dict, cfg: ModelConfig, x: jnp.ndarray) -> Tuple[jnp.ndarra
     B, S, D = x.shape
     xf = x.reshape(-1, D)
     top_e, top_w = route(p, cfg, xf)                            # (T,k)
-    out, _, dropped = held_experts_part(p, cfg, xf, top_e, top_w,
-                                        cfg.first_held_expert, cfg.experts_held)
+    out, _, dropped = held_experts_part(p, cfg, xf, top_e, top_w, 0,
+                                        cfg.experts_held)
     load = jnp.bincount(top_e.reshape(-1), length=cfg.num_experts)
-    stats = {"expert_load": load, "dropped": dropped}
+    stats = {"expert_load": load, "dropped": dropped,
+             "row_pairs": row_route_counts(cfg, top_e, B)}
     if cfg.num_shared_experts > 0:
         out = out + dense_ffn(p["shared"], cfg, x).reshape(-1, D)
     return out.reshape(B, S, D), stats
@@ -232,7 +238,7 @@ def moe_ffn_replicated(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
         xf = xl.reshape(-1, D)
         C = _capacity(cfg, Bl * Sl, P)
         top_e, top_w = route(pl, cfg, xf)                       # identical on all shards
-        e0 = cfg.first_held_expert + jax.lax.axis_index(axis_name) * E_local
+        e0 = jax.lax.axis_index(axis_name) * E_local
         out, gs, dropped = held_experts_part(pl, cfg, xf, top_e, top_w, e0,
                                              E_local, capacity=C)
         out = jax.lax.psum(out, axis_name)                      # the monoid combine
@@ -241,16 +247,18 @@ def moe_ffn_replicated(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
             jnp.zeros((E,), jnp.int32).at[e0 + jnp.arange(E_local)].set(gs),
             stat_axes)
         dropped = jax.lax.psum(dropped, stat_axes)
-        return out.reshape(Bl, Sl, D), load, dropped
+        return (out.reshape(Bl, Sl, D), load, dropped,
+                row_route_counts(cfg, top_e, Bl))
 
     xspec = Pspec(batch_axes if batch_axes else None)
     fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, Pspec(), Pspec(axis_name), Pspec(axis_name), Pspec(axis_name)),
-        out_specs=(xspec, Pspec(), Pspec()),
+        out_specs=(xspec, Pspec(), Pspec(), xspec),
         check_vma=False)
-    out, load, dropped = fn(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
-    stats = {"expert_load": load, "dropped": dropped}
+    out, load, dropped, rows = fn(x, p["router"], p["we_gate"], p["we_up"],
+                                  p["we_down"])
+    stats = {"expert_load": load, "dropped": dropped, "row_pairs": rows}
     if cfg.num_shared_experts > 0:
         out = out + dense_ffn(p["shared"], cfg, x)
     return out, stats
@@ -346,7 +354,8 @@ def moe_ffn_a2a(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
         stat_axes = batch_axes + (axis_name,)
         load = jax.lax.psum(load, stat_axes)
         dropped = jax.lax.psum(dropped, stat_axes)
-        return out.reshape(Bl, Sl, D), load, dropped
+        rows = jax.lax.psum(row_route_counts(cfg, top_e, Bl), axis_name)
+        return out.reshape(Bl, Sl, D), load, dropped, rows
 
     # tokens partitioned: batch over the data axes AND seq over the expert axis
     xspec = Pspec(batch_axes if batch_axes else None, axis_name)
@@ -354,10 +363,11 @@ def moe_ffn_a2a(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
         body, mesh=mesh,
         in_specs=(xspec, Pspec(), Pspec(axis_name), Pspec(axis_name),
                   Pspec(axis_name)),
-        out_specs=(xspec, Pspec(), Pspec()),
+        out_specs=(xspec, Pspec(), Pspec(), Pspec(xspec[0])),
         check_vma=False)
-    out, load, dropped = fn(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
-    stats = {"expert_load": load, "dropped": dropped}
+    out, load, dropped, rows = fn(x, p["router"], p["we_gate"], p["we_up"],
+                                  p["we_down"])
+    stats = {"expert_load": load, "dropped": dropped, "row_pairs": rows}
     if cfg.num_shared_experts > 0:
         out = out + dense_ffn(p["shared"], cfg, x)
     return out, stats
@@ -365,7 +375,11 @@ def moe_ffn_a2a(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
 
 def moe_ffn(p: Dict, cfg: ModelConfig, x: jnp.ndarray, *, mesh=None,
             impl: str = "replicated", axis_name: str = "model") -> Tuple[jnp.ndarray, Dict]:
-    """Dispatch to the configured expert-parallel strategy."""
+    """Dispatch to the configured expert-parallel strategy.
+
+    -> (out, stats): ``expert_load`` (num_experts,) and ``dropped`` () sum
+    over the tokens; ``row_pairs`` (B, 1 + held) is each batch row's
+    :func:`row_route_counts`."""
     if mesh is None or axis_name not in getattr(mesh, "shape", {}) \
             or mesh.shape.get(axis_name, 1) == 1 \
             or cfg.experts_held % max(mesh.shape.get(axis_name, 1), 1) != 0:

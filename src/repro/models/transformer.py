@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from .common import ModelConfig, ParamBuilder, dense, embed_lookup, rms_norm
 from . import attention as attn
 from . import moe as moe_mod
+from .moe import ROUTE_KEY
 from . import ssm
 from ..dist import sharding as shd
 
@@ -172,7 +173,7 @@ def _apply_ffn(p: Dict, cfg: ModelConfig, ftype: str, x: jnp.ndarray,
                counts: Optional[jnp.ndarray] = None):
     """-> (x, stats, counts).  A MoE layer adds its router statistics to
     ``stats`` and, where the decode path passes them, each batch row's
-    routed pairs to ``counts`` (:func:`moe.row_route_counts`)."""
+    routed pairs to ``counts`` (its ``row_pairs``)."""
     if ftype == "none":
         return x, stats, counts
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
@@ -180,13 +181,11 @@ def _apply_ffn(p: Dict, cfg: ModelConfig, ftype: str, x: jnp.ndarray,
         out = moe_mod.dense_ffn(p["ffn"], cfg, h)
     else:
         out, s = moe_mod.moe_ffn(p["ffn"], cfg, h, mesh=ctx.mesh, impl=ctx.moe_impl)
+        rows = s.pop("row_pairs")
         if stats is not None:
             stats = jax.tree_util.tree_map(jnp.add, stats, s)
         if counts is not None:
-            # the layer's own routing, recomputed on the same input: XLA
-            # computes it once for both
-            top_e, _ = moe_mod.route(p["ffn"], cfg, h.reshape(-1, h.shape[-1]))
-            counts = counts + moe_mod.row_route_counts(cfg, top_e, h.shape[0])
+            counts = counts + rows
     return x + out, stats, counts
 
 
@@ -364,14 +363,10 @@ def loss_fn(params: Pytree, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
 # ---------------------------------------------------------------------------
 
 # A model with MoE layers keeps, beside its decode caches, each batch row's
-# running routing counts: (B, 1 + experts_held) int32, the (token, expert)
-# pairs routed in every MoE layer, then those routed to each held expert
-# (``moe.row_route_counts``).  A Sum monoid: every decode_step adds the
-# pairs of its L tokens, so an extend and L one-token steps agree.  The
-# serving engine reads it under the same name (``runtime.engine.ROUTE_KEY``).
-ROUTE_KEY = "route"
-
-
+# routing counts of its last decode step under ``moe.ROUTE_KEY``:
+# (B, 1 + experts_held) int32, the (token, expert) pairs routed in every MoE
+# layer, then those routed to each held expert (``moe.row_route_counts``),
+# summed through the layer scan as a Sum monoid.
 def _has_moe(cfg: ModelConfig) -> bool:
     return any(cfg.ffn_type(i) == "moe" for i in range(cfg.num_layers))
 
@@ -548,8 +543,8 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
     scan the L columns.  Only the last column is unembedded, so an extend
     never holds (B, L, V) logits.  Rows that would fall past the cache's
     end are dropped (:func:`attention.cache_span_update`).  A model with
-    MoE layers adds the step's routed pairs to each row's ``ROUTE_KEY``
-    counts, summed through the layer scan.
+    MoE layers writes the step's routed pairs, summed over its layers, to
+    each row's ``ROUTE_KEY`` counts.
     """
     B, L = tokens.shape
     pos = cache["pos"]
@@ -586,7 +581,7 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
             params[f"rem_{i}"], cfg, lt, ft, x, cache[f"rem_{i}"], pos, ctx,
             counts)
     if counts is not None:
-        new_cache[ROUTE_KEY] = cache[ROUTE_KEY] + counts
+        new_cache[ROUTE_KEY] = counts
 
     if L > 1:
         x = x[:, -1:]

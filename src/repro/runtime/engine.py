@@ -69,6 +69,7 @@ from ..core.plan import Plan, execute_fold, plan_fold
 from . import spans
 from .batcher import Request, RequestBatcher
 from .prefix_cache import PrefixCache, PrefixCacheConfig, PrefixHit
+from ..models.moe import ROUTE_KEY
 
 # ---------------------------------------------------------------------------
 # the per-request metrics fold (request slot == segment id)
@@ -79,9 +80,6 @@ from .prefix_cache import PrefixCache, PrefixCacheConfig, PrefixHit
 # stop condition as a summed indicator (eos_hits > 0 <=> OR of eos hits)
 METRIC_COLS = ("logprob_sum", "tokens", "eos_hits")
 
-# the cache leaf in which a mixture-of-experts backend keeps each row's
-# running routing counts (``models.transformer.ROUTE_KEY``)
-ROUTE_KEY = "route"
 # the cache leaves that are not KV rows: positions and route counts
 _NOT_KV = ("pos", ROUTE_KEY)
 
@@ -317,11 +315,11 @@ class EngineBackend:
     leaves carry the batch dim at axis 0 (axis 1 under the ``stacked_key``
     subtree) plus a ``pos`` leaf — scalar, or ``(batch,)`` when
     ``pos_per_slot`` (the rolling cache).  A mixture-of-experts model may
-    add a ``route`` leaf (:data:`ROUTE_KEY`): ``(batch, 1 + held)`` int32
-    running sums of each row's routed (token, expert) pairs, then of those
-    routed to each expert it holds; the engine adds each decode step's
-    growth on the occupied slots to :class:`EngineStats` (``routed_pairs``,
-    ``held_pairs``, ``held_expert_max``).
+    add a ``route`` leaf (``models.moe.ROUTE_KEY``): ``(batch, 1 + held)``
+    int32, each row's (token, expert) pairs routed in the last ``decode``
+    call, then those routed to each expert it holds; the engine adds each
+    decode step's counts on the occupied slots to :class:`EngineStats`
+    (``routed_pairs``, ``held_pairs``, ``held_expert_max``).
     """
 
     decode: Callable[[Any, Any, jnp.ndarray], Tuple[jnp.ndarray, Any]]
@@ -494,18 +492,16 @@ class ContinuousEngine:
             return jax.vmap(one)(seeds, tok_idx, logits).astype(jnp.int32)
 
         def step_impl(params, cache, cur, active, seeds, tok_idx, table):
-            before = cache.get(ROUTE_KEY)
             logits, cache = decode(params, cache, cur)
             sampled = sample_rows(logits, seeds, tok_idx)
             rows = metric_rows(logits, sampled, eos)
             table = fold_decode_metrics(
                 table, rows, jnp.arange(S, dtype=jnp.int32), active, S)
-            if before is not None:
+            if ROUTE_KEY in cache:
                 # the step's route counts over the occupied slots ride in
                 # the one transfer the step makes, after the sampled ids
-                grown = jnp.where(active[:, None],
-                                  cache[ROUTE_KEY] - before, 0).sum(0)
-                sampled = jnp.concatenate([sampled, grown.astype(jnp.int32)])
+                counts = jnp.where(active[:, None], cache[ROUTE_KEY], 0).sum(0)
+                sampled = jnp.concatenate([sampled, counts.astype(jnp.int32)])
             return cache, sampled, table
 
         self._step_fn = jax.jit(step_impl, donate_argnums=(1,))
@@ -991,14 +987,14 @@ class ContinuousEngine:
         if retired:
             self._retire(retired, events, now)
 
-    def _count_routes(self, grown: np.ndarray) -> None:
+    def _count_routes(self, counts: np.ndarray) -> None:
         """One decode step's route counts: pairs routed, then per held
-        expert (see :data:`ROUTE_KEY`)."""
+        expert (see ``models.moe.ROUTE_KEY``)."""
         if self._held_pairs is None:
-            self._held_pairs = np.zeros(grown.shape[0] - 1, np.int64)
-        self._held_pairs += grown[1:]
-        self.stats.routed_pairs += int(grown[0])
-        self.stats.held_pairs += int(grown[1:].sum())
+            self._held_pairs = np.zeros(counts.shape[0] - 1, np.int64)
+        self._held_pairs += counts[1:]
+        self.stats.routed_pairs += int(counts[0])
+        self.stats.held_pairs += int(counts[1:].sum())
         self.stats.held_expert_max = int(self._held_pairs.max())
 
     def _dispatch(self, events: List[StreamEvent]) -> List[StreamEvent]:

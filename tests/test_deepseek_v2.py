@@ -141,14 +141,14 @@ def test_n_group_1_routes_exactly_as_before(arch):
 # held experts: one chip's share of the layer
 # ---------------------------------------------------------------------------
 
-def _dense_held(p, cfg, x):
-    """The held experts' part computed densely: every held expert on every
-    token, times the token's gate for it (0 where not routed there)."""
+def _dense_held(p, cfg, x, e0=0):
+    """The part of experts e0 .. e0 + held - 1 computed densely: every held
+    expert on every token, times the token's gate for it (0 where not
+    routed there)."""
     xf = x.reshape(-1, cfg.d_model)
     e, w = M.route(p, cfg, xf)
     g = (jax.nn.one_hot(e, cfg.num_experts) * w[..., None]).sum(1)
-    first = cfg.first_held_expert
-    g = g[:, first:first + cfg.experts_held]
+    g = g[:, e0:e0 + p["we_gate"].shape[0]]
     a = jnp.einsum("td,edf->etf", xf, p["we_gate"])
     u = jnp.einsum("td,edf->etf", xf, p["we_up"])
     y = jnp.einsum("etf,efd->etd", jax.nn.silu(a) * u, p["we_down"])
@@ -163,34 +163,34 @@ def test_chip_shares_add_up_to_the_uncut_layer():
     p = _moe_params(cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, cfg.d_model))
     whole, _ = M.moe_ffn_local(p, cfg, x)
-    shared = M.dense_ffn(p["shared"], cfg, x)
-    total = shared
+    xf = x.reshape(-1, cfg.d_model)
+    e, w = M.route(p, cfg, xf)
+    total = M.dense_ffn(p["shared"], cfg, x)
     for chip in range(4):
-        share = dataclasses.replace(cfg, num_held_experts=4,
-                                    first_held_expert=4 * chip)
         held = {k: v[4 * chip:4 * chip + 4] if k.startswith("we_") else v
                 for k, v in p.items()}
-        part, _ = M.moe_ffn_local(held, share, x)
-        np.testing.assert_allclose(np.asarray(part - shared),
-                                   np.asarray(_dense_held(held, share, x)),
-                                   **F32_TOL)
-        total = total + (part - shared)
+        part, _, _ = M.held_experts_part(held, cfg, xf, e, w, 4 * chip, 4)
+        part = part.reshape(x.shape)
+        np.testing.assert_allclose(
+            np.asarray(part), np.asarray(_dense_held(held, cfg, x, 4 * chip)),
+            **F32_TOL)
+        total = total + part
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                **F32_TOL)
 
 
 def test_every_pair_is_kept_when_all_tokens_route_to_one_held_expert():
-    cfg = _smoke(num_held_experts=4, first_held_expert=4)
+    cfg = _smoke(num_held_experts=4)
     p = _moe_params(cfg)
-    # expert 5 (held) wins every token by far; the other two picks follow
-    p = dict(p, router=p["router"].at[:, 5].set(10.0 / cfg.d_model))
+    # expert 1 (held) wins every token by far; the other two picks follow
+    p = dict(p, router=p["router"].at[:, 1].set(10.0 / cfg.d_model))
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (4, 16,
                                                           cfg.d_model)))
     xf = x.reshape(-1, cfg.d_model)
     e, w = M.route(p, cfg, xf)
-    assert bool((e[:, 0] == 5).all())
-    out, load, dropped = M.held_experts_part(p, cfg, xf, e, w, 4, 4)
-    want = [int((np.asarray(e) == 4 + j).sum()) for j in range(4)]
+    assert bool((e[:, 0] == 1).all())
+    out, load, dropped = M.held_experts_part(p, cfg, xf, e, w, 0, 4)
+    want = [int((np.asarray(e) == j).sum()) for j in range(4)]
     assert int(load[1]) == xf.shape[0] and list(np.asarray(load)) == want
     assert int(dropped) == 0
     shared = M.dense_ffn(p["shared"], cfg, x)
@@ -243,7 +243,7 @@ def _serve(eng, n=5):
 
 
 def test_engine_counts_routed_and_held_pairs_of_decode_steps():
-    cfg = _smoke(num_held_experts=4, first_held_expert=4)
+    cfg = _smoke(num_held_experts=4)
     params, _ = init_params(cfg, KEY)
     eng = _engine(cfg, params)
     _serve(eng)

@@ -11,6 +11,7 @@ from repro.configs import get_config
 from repro.models import (ModelConfig, ParamBuilder, RunCtx, decode_step,
                           forward, init_cache, init_params, loss_fn, unembed)
 from repro.models import attention as A
+from repro.models.moe import ROUTE_KEY
 
 KEY = jax.random.PRNGKey(1)
 
@@ -187,9 +188,14 @@ def _extend_vs_steps(cfg, starts, per_row, L, max_seq):
     cache = _history_cache(cfg, params, B, max_seq, starts, per_row)
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, L), 0,
                               cfg.vocab_size)
-    ref = cache
+    ref, routed = cache, 0
     for j in range(L):
         ref_logits, ref = _STEP(params, cfg, ref, toks[:, j:j + 1])
+        routed = routed + ref.get(ROUTE_KEY, 0)
+    if ROUTE_KEY in ref:
+        # a MoE model's route counts are those of the last call: an
+        # extend's are its L one-token steps' together
+        ref = {**ref, ROUTE_KEY: routed}
     got_logits, got = _STEP(params, cfg, cache, toks)
     return ref_logits, ref, got_logits, got
 
