@@ -256,77 +256,65 @@ def decode_positions(pos: jnp.ndarray, batch: int,
     return first + jnp.arange(length, dtype=jnp.int32)
 
 
-def cache_span_update(cache: jnp.ndarray, new: jnp.ndarray,
-                      pos: jnp.ndarray, *, seq_axis: int = 1) -> jnp.ndarray:
-    """Write a contiguous span of rows into a cache at scalar or per-row
-    positions.
+def cache_write(cache: jnp.ndarray, new: jnp.ndarray, pos: jnp.ndarray,
+                layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Write the step's new rows into a position-indexed cache.
 
-    cache: (..., S, ...) with the sequence axis at ``seq_axis``; new is the
-    same shape with span length L <= S in place of S; pos: () or (B,) start
-    positions (the batch axis is ``seq_axis - 1`` for the per-row form).
-    The single-row case (L == 1) is the classic decode write.  For L > 1
-    (an extend) rows that would land at or past S are DROPPED: a bare
-    ``dynamic_update_slice`` would clamp the start and shift every row of
-    the span, real ones included, to the wrong positions.
+    cache: (B, S, ...), or with ``layer`` a stack (n, B, S, ...) of which
+    layer ``layer`` is written; new: (B, L, ...); pos: () or (B,) start
+    positions.  Row j of batch row b lands at position ``pos[b] + j``
+    (:func:`decode_positions`); rows that would land at or past S are
+    DROPPED, never shifted onto real ones.  One scatter of the B x L rows
+    and nothing else: every other row of the cache, every other layer's
+    included, is left as it was, so a donated cache or a loop-carried stack
+    is updated in place.
     """
-    L, S = new.shape[seq_axis], cache.shape[seq_axis]
-
-    def write(c, n, i, axis):
-        if L == 1:
-            return jax.lax.dynamic_update_slice_in_dim(c, n, i, axis=axis)
-        start = jnp.minimum(i, S - L)
-        shift = i - start                      # rows of the span past S
-        old = jax.lax.dynamic_slice_in_dim(c, start, L, axis=axis)
-        moved = jnp.roll(n, shift, axis=axis)  # row j <- new row j - shift
-        keep = jnp.arange(L) >= shift
-        keep = keep.reshape((L,) + (1,) * (n.ndim - axis - 1))
-        return jax.lax.dynamic_update_slice_in_dim(
-            c, jnp.where(keep, moved, old), start, axis=axis)
-
-    if jnp.ndim(pos) == 1:
-        return jax.vmap(
-            lambda c, n, i: write(c, n, i, seq_axis - 1),
-            in_axes=(seq_axis - 1, seq_axis - 1, 0),
-            out_axes=seq_axis - 1)(cache, new, pos)
-    return write(cache, new, pos, seq_axis)
+    B, L = new.shape[:2]
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    cols = decode_positions(pos, B, L)
+    index = (rows, cols) if layer is None else (layer, rows, cols)
+    return cache.at[index].set(new, mode="drop", unique_indices=True)
 
 
-def cache_row_update(cache: jnp.ndarray, new: jnp.ndarray,
-                     pos: jnp.ndarray) -> jnp.ndarray:
-    """Write new-token rows into a cache at scalar or per-row positions.
-
-    cache: (B, S, ...); new: (B, L, ...); pos: () or (B,).  The per-row form
-    is a vmapped dynamic_update_slice — each request slot writes at its own
-    position (continuous batching).  :func:`cache_span_update` on the
-    sequence axis.
-    """
-    return cache_span_update(cache, new, pos, seq_axis=1)
+def cache_layer(cache: jnp.ndarray,
+                layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Layer ``layer`` of a stacked (n, ...) cache leaf; the leaf itself
+    when ``layer`` is None."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
 
 
 def attention_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                      cache: Tuple[jnp.ndarray, jnp.ndarray], pos: jnp.ndarray,
                      *, window: Optional[int] = None,
+                     layer: Optional[jnp.ndarray] = None,
                      kv_shards: int = 1) -> Tuple[jnp.ndarray, Tuple]:
-    """One decode step. x: (B, L, D); cache: (k, v) each (B, S, KV, hd);
-    pos: () current position (tokens 0..pos-1 are valid in the cache), or
-    (B,) per-slot positions for a continuously-batched cache — each row
-    writes and masks at its own position, so rows stay independent and a
-    reused slot's computation is identical to a fresh batch's.  L > 1 is an
-    extend: the L tokens at pos..pos+L-1 are written and attend causally
-    over the cache in one pass (scores (B, H, L, S)).
+    """One decode step. x: (B, L, D); cache: (k, v) each (B, S, KV, hd), or
+    each a stack (n, B, S, KV, hd) of which this is layer ``layer`` (see
+    :func:`cache_write`); pos: () current position (tokens 0..pos-1 are
+    valid in the cache), or (B,) per-slot positions for a continuously-
+    batched cache — each row writes and masks at its own position, so rows
+    stay independent and a reused slot's computation is identical to a
+    fresh batch's.  L > 1 is an extend: the L tokens at pos..pos+L-1 are
+    written and attend causally over the cache in one pass (scores (B, H,
+    L, S)).  Returns the cache as it was given, with the new rows written.
 
     kv_shards > 1 requests flash-decoding: the KV cache's sequence axis is
     sharded over the 'model' mesh axis and partial attn_states are merged
     with the monoid (sequence-parallel decode for long_500k).
     """
-    kcache, vcache = cache
-    B, S = kcache.shape[0], kcache.shape[1]
+    kstack, vstack = cache
+    B = x.shape[0]
     positions = decode_positions(pos, B, x.shape[1])
     q, k_new, v_new = _project_qkv(p, cfg, x, positions)
-    kcache = cache_row_update(kcache, k_new, pos)
-    vcache = cache_row_update(vcache, v_new, pos)
-    kcache = shd.act(kcache, ("batch", "kv_seq", "kv_heads", None))
-    vcache = shd.act(vcache, ("batch", "kv_seq", "kv_heads", None))
+    kstack = cache_write(kstack, k_new, pos, layer)
+    vstack = cache_write(vstack, v_new, pos, layer)
+    kcache = shd.act(cache_layer(kstack, layer),
+                     ("batch", "kv_seq", "kv_heads", None))
+    vcache = shd.act(cache_layer(vstack, layer),
+                     ("batch", "kv_seq", "kv_heads", None))
+    S = kcache.shape[1]
 
     k_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     scale = 1.0 / math.sqrt(cfg.head_dim)
@@ -336,7 +324,7 @@ def attention_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
     o = _gqa_values(w, vcache)                                  # (B,L,H,hd)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
-    return shd.act(out, ("batch", None, "embed")), (kcache, vcache)
+    return shd.act(out, ("batch", None, "embed")), (kstack, vstack)
 
 
 def flash_decode_shardmap(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
@@ -553,10 +541,12 @@ def mla_attention(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
 @jax.named_scope("mla")
 def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
                cache: Tuple[jnp.ndarray, jnp.ndarray],
-               pos: jnp.ndarray) -> Tuple[jnp.ndarray, Tuple]:
+               pos: jnp.ndarray,
+               layer: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, Tuple]:
     """Weight-absorbed MLA decode: attention runs in the latent space.
 
-    cache = (c (B,S,kvl), k_rope (B,S,dr)). Per step:
+    cache = (c (B,S,kvl), k_rope (B,S,dr)), or stacks of them of which this
+    is layer ``layer``, written as :func:`attention_decode` writes. Per step:
       q_nope' = q_nope @ wk_b^T  (absorb the k up-projection into q)
       scores  = q_nope' . c  +  q_rope . k_rope
       o_latent = softmax(scores) @ c          (B,1,H,kvl)
@@ -566,15 +556,16 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     memory-term win reported in the roofline table.  x may hold L > 1
     tokens (an extend), masked causally as in :func:`attention_decode`.
     """
-    c_cache, r_cache = cache
-    B, S = c_cache.shape[0], c_cache.shape[1]
+    c_stack, r_stack = cache
+    B = x.shape[0]
     positions = decode_positions(pos, B, x.shape[1])
     q_nope, q_rope = _mla_q(p, cfg, x, positions)               # (B,L,H,*)
     c_new, r_new = _mla_latent(p, cfg, x, positions)
-    c_cache = cache_row_update(c_cache, c_new, pos)
-    r_cache = cache_row_update(r_cache, r_new, pos)
-    c_cache = shd.act(c_cache, ("batch", "kv_seq", None))
-    r_cache = shd.act(r_cache, ("batch", "kv_seq", None))
+    c_stack = cache_write(c_stack, c_new, pos, layer)
+    r_stack = cache_write(r_stack, r_new, pos, layer)
+    c_cache = shd.act(cache_layer(c_stack, layer), ("batch", "kv_seq", None))
+    r_cache = shd.act(cache_layer(r_stack, layer), ("batch", "kv_seq", None))
+    S = c_cache.shape[1]
 
     # absorb wk_b into q: (B,L,H,dn) x (kvl,H,dn) -> (B,L,H,kvl)
     q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, p["wk_b"].astype(x.dtype))
@@ -591,4 +582,4 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     o_lat = jnp.einsum("bhqs,bsl->bqhl", w, c_cache)            # (B,L,H,kvl)
     o = jnp.einsum("bqhl,lhv->bqhv", o_lat, p["wv_b"].astype(x.dtype))
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
-    return shd.act(out, ("batch", None, "embed")), (c_cache, r_cache)
+    return shd.act(out, ("batch", None, "embed")), (c_stack, r_stack)

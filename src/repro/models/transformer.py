@@ -464,22 +464,48 @@ def init_cache(params: Pytree, cfg: ModelConfig, batch: int, max_seq: int, *,
 
 
 def _extends_in_parallel(ltype: str, ctx: RunCtx) -> bool:
-    """Whether a layer of this kind takes L > 1 new tokens in one pass.
+    """Whether a layer of this kind takes L > 1 new tokens in one pass, and
+    so writes its cache where it lies.
 
-    Attention and MLA over a position-indexed cache write L rows and mask
-    causally by position; cross-attention has no mask and no cache write.
-    Recurrent state (mamba, mLSTM, sLSTM) and the sequence-sharded flash
-    decode, which writes one row on its owning shard, take one token at a
-    time."""
+    Attention and MLA over a position-indexed cache write L rows in place
+    (:func:`attention.cache_write`) and mask causally by position;
+    cross-attention has no mask and only reads its context KV.  Recurrent
+    state (mamba, mLSTM, sLSTM) and the sequence-sharded flash decode, which
+    writes one row on its owning shard, take one token at a time, on their
+    own layer's cache."""
     if ltype in ("attn", "local"):
         return not (ctx.decode_impl == "flash" and ctx.mesh is not None)
     return ltype == "xattn"
 
 
+def _context_kv(cache: Dict, layer: Optional[jnp.ndarray]):
+    """The layer's projected context (k, v), read and never written."""
+    return (attn.cache_layer(cache["xk"], layer),
+            attn.cache_layer(cache["xv"], layer))
+
+
 def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
                   x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
-                  ctx: RunCtx, counts: Optional[jnp.ndarray]):
-    """-> (x, cache, counts): see :func:`_apply_ffn` for ``counts``."""
+                  ctx: RunCtx, counts: Optional[jnp.ndarray],
+                  layer: Optional[jnp.ndarray] = None):
+    """-> (x, cache, counts): see :func:`_apply_ffn` for ``counts``.
+
+    ``cache`` holds this layer's leaves, or with ``layer`` the stacked
+    leaves (n, B, ...) of the scanned region, of which this is layer
+    ``layer``.  What is written follows the leaf: position-indexed rows
+    (attention ``k``/``v``, MLA ``lat_c``/``lat_r``) get only the step's new
+    rows, in place; context KV (``xk``/``xv``) is only read; recurrent state
+    and the sharded flash decode's KV are read for this layer and written
+    back whole."""
+    if layer is not None and not _extends_in_parallel(ltype, ctx):
+        own = jax.tree_util.tree_map(lambda c: attn.cache_layer(c, layer),
+                                     cache)
+        x, own, counts = _decode_layer(p, cfg, ltype, ftype, x, own, pos,
+                                       ctx, counts)
+        cache = jax.tree_util.tree_map(
+            lambda c, o: jax.lax.dynamic_update_index_in_dim(c, o, layer, 0),
+            cache, own)
+        return x, cache, counts
     L = x.shape[1]
     if L > 1 and not _extends_in_parallel(ltype, ctx):
         # scan the L columns one token at a time through this layer
@@ -497,7 +523,8 @@ def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
     if ltype in ("attn", "local"):
         window = cfg.sliding_window if ltype == "local" else None
         if cfg.use_mla:
-            mixed, (c, r) = attn.mla_decode(p["mix"], cfg, h, (cache["lat_c"], cache["lat_r"]), pos)
+            mixed, (c, r) = attn.mla_decode(
+                p["mix"], cfg, h, (cache["lat_c"], cache["lat_r"]), pos, layer)
             cache = {**cache, "lat_c": c, "lat_r": r}
         elif ctx.decode_impl == "flash" and ctx.mesh is not None:
             mixed, (k, v) = attn.flash_decode_shardmap(
@@ -506,11 +533,12 @@ def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
             cache = {**cache, "k": k, "v": v}
         else:
             mixed, (k, v) = attn.attention_decode(
-                p["mix"], cfg, h, (cache["k"], cache["v"]), pos, window=window)
+                p["mix"], cfg, h, (cache["k"], cache["v"]), pos,
+                window=window, layer=layer)
             cache = {**cache, "k": k, "v": v}
     elif ltype == "xattn":
-        mixed = attn.cross_attention(p["mix"], cfg, h, (cache["xk"], cache["xv"]),
-                                     gated=True)
+        mixed = attn.cross_attention(p["mix"], cfg, h,
+                                     _context_kv(cache, layer), gated=True)
     elif ltype == "mamba":
         mixed, new = ssm.mamba_decode(p["mix"], cfg, h, cache)
         cache = new
@@ -525,7 +553,8 @@ def _decode_layer(p: Dict, cfg: ModelConfig, ltype: str, ftype: str,
     x = x + mixed
     if cfg.decoder_cross_attn and ltype in ("attn", "local") and "xk" in cache:
         h = rms_norm(x, p["norm_x"], cfg.norm_eps)
-        x = x + attn.cross_attention(p["xattn"], cfg, h, (cache["xk"], cache["xv"]))
+        x = x + attn.cross_attention(p["xattn"], cfg, h,
+                                     _context_kv(cache, layer))
     x, _, counts = _apply_ffn(p, cfg, ftype, x, ctx, None, counts)
     return x, cache, counts
 
@@ -542,7 +571,7 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
     parallel pass, and layers that cannot (see :func:`_extends_in_parallel`)
     scan the L columns.  Only the last column is unembedded, so an extend
     never holds (B, L, V) logits.  Rows that would fall past the cache's
-    end are dropped (:func:`attention.cache_span_update`).  A model with
+    end are dropped (:func:`attention.cache_write`).  A model with
     MoE layers writes the step's routed pairs, summed over its layers, to
     each row's ``ROUTE_KEY`` counts.
     """
@@ -562,19 +591,21 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
             ctx, counts)
 
     if period_slots:
-        def body(carry, inp):
-            x, counts = carry
-            slot_params, slot_cache = inp
-            new_slots = {}
+        # the stacked cache rides in the carry, beside the layer index, and
+        # each layer writes into it where it lies: with the cache donated
+        # the loop updates the caller's buffer, never a copy of the stack
+        def body(carry, slot_params):
+            x, counts, stack, i = carry
+            stack = dict(stack)
             for j, (lt, ft) in enumerate(period_slots):
-                x, new_slots[f"slot_{j}"], counts = _decode_layer(
+                x, stack[f"slot_{j}"], counts = _decode_layer(
                     slot_params[f"slot_{j}"], cfg, lt, ft, x,
-                    slot_cache[f"slot_{j}"], pos, ctx, counts)
-            return (x, counts), new_slots
+                    stack[f"slot_{j}"], pos, ctx, counts, layer=i)
+            return (x, counts, stack, i + 1), None
 
-        (x, counts), new_layer_cache = jax.lax.scan(
-            body, (x, counts), (params["layers"], cache["layers"]))
-        new_cache["layers"] = new_layer_cache
+        (x, counts, new_cache["layers"], _), _ = jax.lax.scan(
+            body, (x, counts, cache["layers"], jnp.int32(0)),
+            params["layers"])
 
     for i, (lt, ft) in enumerate(remainder):
         x, new_cache[f"rem_{i}"], counts = _decode_layer(

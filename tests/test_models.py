@@ -11,6 +11,8 @@ from repro.configs import get_config
 from repro.models import (ModelConfig, ParamBuilder, RunCtx, decode_step,
                           forward, init_cache, init_params, loss_fn, unembed)
 from repro.models import attention as A
+from repro.models import transformer as T
+from repro.models.common import embed_lookup, rms_norm
 from repro.models.moe import ROUTE_KEY
 
 KEY = jax.random.PRNGKey(1)
@@ -260,3 +262,87 @@ def test_extend_past_cache_end_drops_pad_rows():
     # the row that fits attends to the same keys either way
     np.testing.assert_allclose(np.asarray(got_logits[0]),
                                np.asarray(ref_logits[0]), **F32_TOL)
+
+
+# ---------------------------------------------------------------------------
+# the stacked cache, carried and written in place == the per-layer scan
+# ---------------------------------------------------------------------------
+
+def _per_layer_scan_step(params, cfg, cache, tokens):
+    """The serving step with the stacked cache threaded through the layer
+    scan as ``xs``/``ys``: each layer's cache is sliced out of the stack,
+    written, and stacked back.  The stack's rows are what ``decode_step``
+    must leave, written in place."""
+    B, L = tokens.shape
+    pos = cache["pos"]
+    x = embed_lookup(params["embed"], tokens).astype(cfg.dtype)
+    if cfg.scale_embed:
+        x = x * np.sqrt(cfg.d_model)
+    new = {"pos": pos + L}
+    counts = jnp.zeros_like(cache[ROUTE_KEY]) if ROUTE_KEY in cache else None
+    prelude, period_slots, remainder = T._layer_plan(cfg)
+    ctx = RunCtx()
+    for i, (lt, ft) in enumerate(prelude):
+        x, new[f"prelude_{i}"], counts = T._decode_layer(
+            params[f"prelude_{i}"], cfg, lt, ft, x, cache[f"prelude_{i}"],
+            pos, ctx, counts)
+
+    def body(carry, inp):
+        x, counts = carry
+        slot_params, slot_cache = inp
+        out = {}
+        for j, (lt, ft) in enumerate(period_slots):
+            x, out[f"slot_{j}"], counts = T._decode_layer(
+                slot_params[f"slot_{j}"], cfg, lt, ft, x,
+                slot_cache[f"slot_{j}"], pos, ctx, counts)
+        return (x, counts), out
+
+    (x, counts), new["layers"] = jax.lax.scan(
+        body, (x, counts), (params["layers"], cache["layers"]))
+    for i, (lt, ft) in enumerate(remainder):
+        x, new[f"rem_{i}"], counts = T._decode_layer(
+            params[f"rem_{i}"], cfg, lt, ft, x, cache[f"rem_{i}"], pos, ctx,
+            counts)
+    if counts is not None:
+        new[ROUTE_KEY] = counts
+    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
+    return unembed(params, cfg, x), new
+
+
+@pytest.mark.parametrize("arch", [
+    "qwen3-0.6b",          # dense GQA: k/v rows written into the stack
+    "deepseek-v2-236b",    # MLA latent rows; a dense prelude layer unstacked
+    "jamba-v0.1-52b",      # mamba state written back whole at its layer
+    "llama-3.2-vision-90b",  # read-only context KV beside attention rows
+])
+def test_stacked_cache_written_in_place_equals_per_layer_scan(arch):
+    """An extend and several decode steps through ``decode_step``, which
+    carries the stacked cache and writes only the new rows, give logits and
+    a whole cache bit-identical to the per-layer scan's."""
+    cfg = get_config(arch, smoke=True)
+    params, _ = init_params(cfg, KEY)
+    B = 2
+    context = None
+    if cfg.decoder_cross_attn or cfg.encoder_layers:
+        context = jax.random.normal(KEY, (B, cfg.num_image_tokens,
+                                          cfg.d_model), cfg.dtype)
+    cache = init_cache(params, cfg, B, 24, context=context,
+                       pos_per_slot=True)
+    cache = {**cache, "pos": jnp.asarray([0, 5], jnp.int32)}
+    got = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
+                  donate_argnums=(1,))
+    want = jax.jit(lambda p, c, t: _per_layer_scan_step(p, cfg, c, t))
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, 12), 0,
+                              cfg.vocab_size)
+    ref = cache
+    cache = jax.tree_util.tree_map(jnp.copy, cache)
+    for cur in (toks[:, :8],) + tuple(toks[:, j:j + 1] for j in range(8, 12)):
+        ref_logits, ref = want(params, ref, cur)
+        logits, cache = got(params, cache, cur)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        assert jax.tree_util.tree_structure(cache) == \
+            jax.tree_util.tree_structure(ref)
+        for a, b in zip(jax.tree_util.tree_leaves(cache),
+                        jax.tree_util.tree_leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

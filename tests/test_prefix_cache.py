@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from _hyp import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
 from repro.core import monoids
 from repro.data.windows import WindowedMetrics
-from repro.models.attention import cache_row_update, cache_span_update
+from repro.models.attention import cache_layer, cache_write
 from repro.serving import (ContinuousEngine, PrefixCache, PrefixCacheConfig,
                            ServeConfig)
 from test_serving import drain, toy_backend, toy_engine
@@ -317,8 +317,23 @@ class TestEnginePrefix:
 
 
 # ---------------------------------------------------------------------------
-# the span scatter primitive
+# the row writer: the step's new rows, scattered where the cache lies
 # ---------------------------------------------------------------------------
+
+def _rows_written(cache, new, pos, layer=None):
+    """Loop reference of :func:`cache_write`: row j of batch row b at
+    ``pos[b] + j``, rows at or past S dropped."""
+    want = np.array(cache)
+    view = want if layer is None else want[layer]
+    B, L = new.shape[:2]
+    S = view.shape[1]
+    for b in range(B):
+        start = int(pos[b]) if np.ndim(pos) else int(pos)
+        for j in range(L):
+            if start + j < S:
+                view[b, start + j] = new[b, j]
+    return want
+
 
 class TestCacheSpanUpdate:
     def test_matches_loop_reference_vector_pos(self):
@@ -326,9 +341,8 @@ class TestCacheSpanUpdate:
         cache = rng.normal(size=(3, 10, 4)).astype(np.float32)
         new = rng.normal(size=(3, 5, 4)).astype(np.float32)
         pos = np.array([0, 2, 5], np.int32)
-        got = np.asarray(cache_span_update(jnp.asarray(cache),
-                                           jnp.asarray(new),
-                                           jnp.asarray(pos)))
+        got = np.asarray(cache_write(jnp.asarray(cache), jnp.asarray(new),
+                                     jnp.asarray(pos)))
         want = cache.copy()
         for b in range(3):
             want[b, pos[b]:pos[b] + 5] = new[b]
@@ -337,13 +351,14 @@ class TestCacheSpanUpdate:
     def test_scalar_pos_and_stacked_axis(self):
         rng = np.random.default_rng(1)
         cache = rng.normal(size=(2, 3, 10, 4)).astype(np.float32)  # (n,B,S,H)
-        new = rng.normal(size=(2, 3, 4, 4)).astype(np.float32)
-        got = np.asarray(cache_span_update(jnp.asarray(cache),
-                                           jnp.asarray(new),
-                                           jnp.int32(3), seq_axis=2))
+        new = rng.normal(size=(3, 4, 4)).astype(np.float32)
+        got = np.asarray(cache_write(jnp.asarray(cache), jnp.asarray(new),
+                                     jnp.int32(3), jnp.int32(1)))
         want = cache.copy()
-        want[:, :, 3:7] = new
+        want[1, :, 3:7] = new
         np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            np.asarray(cache_layer(jnp.asarray(got), jnp.int32(1))), want[1])
 
     @pytest.mark.parametrize("per_row", [False, True],
                              ids=["scalar", "per_row"])
@@ -352,9 +367,8 @@ class TestCacheSpanUpdate:
         cache = rng.normal(size=(2, 10, 3)).astype(np.float32)
         new = rng.normal(size=(2, 4, 3)).astype(np.float32)
         pos = np.array([1, 8], np.int32) if per_row else np.int32(8)
-        got = np.asarray(cache_span_update(jnp.asarray(cache),
-                                           jnp.asarray(new),
-                                           jnp.asarray(pos)))
+        got = np.asarray(cache_write(jnp.asarray(cache), jnp.asarray(new),
+                                     jnp.asarray(pos)))
         want = cache.copy()
         for b in range(2):
             start = int(pos[b]) if per_row else int(pos)
@@ -363,9 +377,41 @@ class TestCacheSpanUpdate:
         np.testing.assert_array_equal(got, want)
 
     def test_single_row_delegates_to_row_update(self):
+        """L = 1, the decode step's write: one row per slot, each at its
+        own position."""
         cache = jnp.zeros((2, 6), jnp.int32)
-        out = cache_row_update(cache, jnp.asarray([[5], [7]], jnp.int32),
-                               jnp.asarray([1, 4], jnp.int32))
+        out = cache_write(cache, jnp.asarray([[5], [7]], jnp.int32),
+                          jnp.asarray([1, 4], jnp.int32))
         np.testing.assert_array_equal(np.asarray(out),
                                       [[0, 5, 0, 0, 0, 0],
                                        [0, 0, 0, 0, 7, 0]])
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["layer", "stack"])
+    @pytest.mark.parametrize("L", [1, 5])
+    @pytest.mark.parametrize("pos", [7, 9, (0, 9, 3), (11, 6, 2)],
+                             ids=["scalar", "scalar_last", "per_row",
+                                  "per_row_past_end"])
+    def test_writes_only_the_new_rows(self, pos, L, stacked):
+        """Scalar and per-row ``pos``, one row or a span, into a layer or a
+        stack: the rows that fit are written, those at or past S dropped
+        (a row starting at S writes nothing), and every other row of the
+        cache, other layers' included, is bit-identical to before."""
+        rng = np.random.default_rng(L)
+        n, B, S = 3, 3, 10
+        shape = (n, B, S, 2, 4) if stacked else (B, S, 2, 4)
+        cache = rng.normal(size=shape).astype(np.float32)
+        new = rng.normal(size=(B, L, 2, 4)).astype(np.float32)
+        pos = np.asarray(pos, np.int32)
+        layer = 2 if stacked else None
+        got = np.asarray(cache_write(
+            jnp.asarray(cache), jnp.asarray(new), jnp.asarray(pos),
+            None if layer is None else jnp.int32(layer)))
+        want = _rows_written(cache, new, pos, layer)
+        np.testing.assert_array_equal(got, want)
+        written = np.zeros(shape[:-2], bool)
+        view = written if layer is None else written[layer]
+        for b in range(B):
+            start = int(pos[b]) if pos.ndim else int(pos)
+            view[b, start:start + L] = True
+        np.testing.assert_array_equal(got[~written], cache[~written])

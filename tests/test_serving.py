@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from _hyp import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
-from repro.models.attention import cache_span_update, decode_positions
+from repro.models.attention import cache_write, decode_positions
 from repro.runtime import RequestBatcher
 from repro.serving import ContinuousEngine, EngineBackend, Request, ServeConfig
 
@@ -45,7 +45,7 @@ def toy_decode(params, cache, cur):
     batch, length = cur.shape
     pos = cache["pos"]
     posb = decode_positions(pos, batch, length)[:, -1]
-    hist = cache_span_update(cache["hist"], cur, pos)
+    hist = cache_write(cache["hist"], cur, pos)
     valid = jnp.arange(hist.shape[1])[None, :] <= posb[:, None]
     s = jnp.sum(hist * valid, axis=1)
     tgt = (s * params["a"] + posb * params["b"]) % VOCAB

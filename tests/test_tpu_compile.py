@@ -89,3 +89,64 @@ def test_max_min_fold(on_tpu, one_chip, monoid):
                      num_segments=keys).local_tier.kind == "kernel"
     _compile(lambda v, s: execute_fold(monoid, v, segment_ids=s,
                                        num_segments=keys), vals, segs)
+
+
+@pytest.mark.parametrize("arch", [
+    "qwen3-0.6b",          # dense GQA decoder
+    "deepseek-v2-236b",    # MLA latent cache, MoE layers, a dense prelude
+    "gemma3-1b",           # sliding-window local layers
+    "jamba-v0.1-52b",      # mamba state beside attention and MoE
+])
+def test_decode_step_writes_the_donated_stack_in_place(on_tpu, one_chip,
+                                                       arch):
+    """The serving step, its cache donated, updates the stacked cache where
+    it lies: its temporaries stay under one layer's cache, and no copy
+    makes a buffer of a stacked leaf's shape (a second stack).
+
+    Smoke depth and widths, but the cache rows at their published widths
+    (a 128-wide head, MLA's 512 + 64 latent), so the compiler tiles them as
+    it tiles the served caches, and two periods, so the stack has layers
+    to slice.  MLA's 64-wide rope rows are the exception: the chip lays a
+    leaf that narrow out sequence-minor, and the row scatter relays that
+    stack out once into the step and once out of it."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import decode_step, init_cache, init_params
+
+    cfg = get_config(arch, smoke=True)
+    cfg = dataclasses.replace(
+        cfg, num_layers=cfg.prelude_dense_layers + 2 * cfg.period,
+        **(dict(kv_lora_rank=512, qk_rope_dim=64,
+                head_dim=cfg.qk_nope_dim + 64) if cfg.use_mla
+           else dict(head_dim=128)))
+    batch, seq = 32, 2048
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))[0])
+    cache = jax.eval_shape(lambda p: init_cache(p, cfg, batch, seq,
+                                                pos_per_slot=True), params)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def step(p, c, t):
+        logits, c = decode_step(p, cfg, c, t)
+        return jnp.argmax(logits[:, -1], -1), c
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    ).compile()
+    stack = jax.tree_util.tree_leaves_with_path(cache["layers"])
+    n = stack[0][1].shape[0]
+    layer_bytes = sum(a.size * a.dtype.itemsize for _, a in stack) // n
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    text = compiled.as_text()
+    for path, a in stack:
+        if path[-1].key == "lat_r":
+            continue
+        dims = ",".join(map(str, a.shape))
+        copy = re.compile(r"= \w+\[" + dims + r"\]\{[^}]*\} copy\(")
+        assert not copy.search(text), (arch, jax.tree_util.keystr(path))
